@@ -29,16 +29,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import logical
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.flash_decode import (
     flash_decode_partials,
     flash_decode_pallas,
     lse_combine,
 )
-from repro.kernels.flash_attention.ops import _on_tpu
 
 
 def _as_axes(axes) -> tuple[str, ...]:
@@ -106,11 +105,11 @@ def flash_decode_sharded(q, k, v, *, kv_len, mesh, seq_axes, batch_axes=(),
         b_l, kvh, group, hd = o_c.shape
         return out.reshape(b_l, kvh * group, hd).reshape(b_l, 1, kvh * group, hd)
 
-    return shard_map(
+    return jax.shard_map(
         local_decode, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -122,8 +121,7 @@ def decode_attention(q, k, v, *, kv_len, bk=512, interpret=None):
     shard_map path runs; otherwise the local split-KV kernel does.  The
     "batch" rule (if bound) carries through as the batch sharding.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     mesh = logical.current_mesh()
     seq_axes = logical.bound_axes("kv_seq")
     if mesh is None or not seq_axes:

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import gnn as gnn_lib
@@ -47,7 +46,7 @@ def _sharded_aggregate(h, edges, mesh, n_nodes, aggregator):
         )[:, None]
         return jax.lax.psum(agg, axes), jax.lax.psum(deg, axes)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), P(None, axes)),
         out_specs=(P(None, None), P(None, None)),
@@ -102,7 +101,7 @@ def apply_batched_sharded(params, batch, cfg, mesh, dp, n_graphs, n_nodes,
         )
         return logits, labels
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, P(dp, None), P(None, dp), P(dp), P(dp), P(dp)),
         out_specs=(P(dp, None), P(dp)),

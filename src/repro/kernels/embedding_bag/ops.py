@@ -1,13 +1,8 @@
-"""Jitted wrapper: Pallas on TPU, interpret-mode Pallas elsewhere."""
+"""Jitted wrapper whose kernel mode comes from ``interpret_mode``."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.embedding_bag.embedding_bag import hot_embedding_bag_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def hot_embedding_bag(table, ids, *, tile_b: int = 128):
@@ -21,6 +16,6 @@ def hot_embedding_bag(table, ids, *, tile_b: int = 128):
 
         ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
     out = hot_embedding_bag_pallas(
-        table, ids, tile_b=tile_b, interpret=not _on_tpu()
+        table, ids, tile_b=tile_b, interpret=interpret_mode()
     )
     return out[:B] if pad else out

@@ -1,21 +1,16 @@
-"""Jitted wrappers: Pallas on TPU, interpret mode elsewhere."""
+"""Jitted wrappers whose kernel mode comes from ``interpret_mode``."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.flash_decode import flash_decode_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, q_offset=0, bq=128, bk=128):
     """Blocked GQA attention: q [B, Tq, H, hd], k/v [B, Tk, KVH, hd]."""
     return flash_attention_pallas(
         q, k, v, causal=causal, q_offset=q_offset, bq=bq, bk=bk,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 
@@ -26,4 +21,4 @@ def flash_decode(q, k, v, *, kv_len, kv_offset=0, bk=512):
     sequence-sharded cache); kv_len masks against global position.
     """
     return flash_decode_pallas(q, k, v, kv_len=kv_len, kv_offset=kv_offset,
-                               bk=bk, interpret=not _on_tpu())
+                               bk=bk, interpret=interpret_mode())

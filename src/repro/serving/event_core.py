@@ -58,8 +58,9 @@ k == 1 Lindley closed form in ``engine`` reassociates; nothing here does.
 
 Determinism: simulated path (see ``repro.analysis``) — no RNG, no wall
 clocks; all state is threaded explicitly.  The optional JAX path runs
-under a scoped ``enable_x64`` so it is float64 end-to-end regardless of
-the process-wide JAX default.
+under a scoped ``jax.enable_x64(True)`` on the CPU device, so it is
+float64 end-to-end regardless of the process-wide JAX default and never
+lands on an accelerator.
 """
 from __future__ import annotations
 
@@ -313,7 +314,9 @@ def _run_fleet_group(items, idxs, k, n_max, out):
         ACT[:n, j] = True
         W0[j, :] = 0.0 if f0 is None else f0
     jax = _jax
-    with jax.experimental.enable_x64():
+    # the simulator's scan stays on the host: float64 (scoped), and off
+    # any accelerator that the served model holds
+    with jax.enable_x64(True), jax.default_device(jax.devices("cpu")[0]):
         Wf, E = _fleet_scan(W0, RT, DT, ACT)
         Wf = np.asarray(Wf)
         E = np.asarray(E)

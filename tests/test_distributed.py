@@ -22,7 +22,8 @@ def run_sub(body: str):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.dist import logical
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        AUTO = jax.sharding.AxisType.Auto
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AUTO,) * 2)
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
@@ -118,7 +119,7 @@ def test_multipod_2x2x2_matches_local():
     from repro.models.embedding import EmbeddingConfig, init_embedding, \\
         embedding_bag_local, embedding_bag
     from repro.dist.loss import ce_loss
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), (AUTO,) * 3)
     rules = {"batch": ("pod", "data"), "model": "model", "vocab": "model"}
 
     cfg = EmbeddingConfig(vocab_sizes=(100, 300, 50), dim=8,
@@ -276,7 +277,7 @@ def test_multipod_lm_train_step_matches_local():
     from repro.launch.steps import build_cell
     arch = get_arch("olmoe-1b-7b")
     cfg = dataclasses.replace(arch.SMOKE, n_layers=2)
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), (AUTO,) * 3)
     cell = build_cell("olmoe-1b-7b", "train_4k", mesh=mesh3,
                       multi_pod=True, cfg_override=cfg)
     assert tuple(cell.rules["batch"]) == ("pod", "data")
