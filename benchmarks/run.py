@@ -1,13 +1,13 @@
 """Benchmark harness: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. The dry-run/roofline numbers
-(EXPERIMENTS.md §Dry-run/§Roofline) come from ``repro.launch.dryrun`` and
-``benchmarks.roofline`` which need a fresh 512-device process each; this
+Prints ``name,us_per_call,derived`` CSV rows, all on the host clock: the
+scheduler, search and cluster simulator, none of them a device metric (the
+chip benchmark is ``benchmarks/chip``).  The dry-run cells come from
+``repro.launch.dryrun``, which needs a fresh 512-device process each; this
 aggregator summarizes their cached artifacts instead of re-lowering.
 """
 from __future__ import annotations
 
-import json
 import pathlib
 
 ART = pathlib.Path(__file__).resolve().parents[1] / "artifacts"
@@ -24,18 +24,6 @@ def _summarize_artifacts() -> None:
             per_mesh[mesh] = per_mesh.get(mesh, 0) + 1
         print(f"dryrun_cells,0.00,compiled={ok};" +
               ";".join(f"{k}={v}" for k, v in sorted(per_mesh.items())))
-    for tag, fname in (("baseline", "baseline_single.json"),
-                       ("optimized", "single.json")):
-        p = ART / "roofline" / fname
-        if p.exists():
-            rows = json.loads(p.read_text())
-            worst = min(rows, key=lambda r: r["roofline_fraction"])
-            by_bn = {}
-            for r in rows:
-                by_bn[r["bottleneck"]] = by_bn.get(r["bottleneck"], 0) + 1
-            print(f"roofline_{tag},0.00,cells={len(rows)};" +
-                  ";".join(f"{k}={v}" for k, v in sorted(by_bn.items())) +
-                  f";worst={worst['arch']}/{worst['shape']}")
 
 
 def main() -> None:
@@ -47,12 +35,10 @@ def main() -> None:
         bench_cluster,
         bench_gradient_search,
         bench_host_scheduling,
-        bench_kernels,
         bench_server_explore,
         bench_task_scheduler,
     )
 
-    bench_kernels.run()
     bench_host_scheduling.run()
     bench_accel_scheduling.run()
     bench_gradient_search.run()

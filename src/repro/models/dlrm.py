@@ -8,6 +8,12 @@ partition is explicit here: ``apply_sparse`` is exactly `G_s` and
 ``apply_dense_given_pooled`` is `G_d`, so the S-D pipeline scheduler can
 launch them as separate stages with the pooled [B, F, D] tensor as the
 intermediate-queue payload.
+
+Each layer runs under a ``jax.named_scope`` (``sparse``, ``dense``,
+``interaction``; ``mlp`` in ``layers.apply_mlp``; ``gather`` and ``pool`` in
+``embedding``), so every compiled instruction's ``op_name`` names the layer
+it belongs to and a device trace can be read by those names.  Whatever
+implements a layer later runs inside its scope.
 """
 from __future__ import annotations
 
@@ -39,28 +45,36 @@ def init(key, cfg: RecsysConfig):
 
 def apply_sparse(params, batch, cfg: RecsysConfig) -> jax.Array:
     """G_s: the SparseNet — multi-hot EmbeddingBag -> pooled [B, F, D]."""
-    return emb_lib.embedding_bag(params["embedding"], batch["sparse_ids"], cfg.embedding)
+    with jax.named_scope("sparse"):
+        return emb_lib.embedding_bag(params["embedding"], batch["sparse_ids"],
+                                     cfg.embedding)
 
 
-def dot_interaction(vectors: jax.Array) -> jax.Array:
-    """Pairwise dots among n feature vectors: [B, n, D] -> [B, n(n-1)/2]."""
-    B, n, _ = vectors.shape
-    z = jnp.einsum("bnd,bmd->bnm", vectors, vectors)
-    iu, ju = jnp.triu_indices(n, k=1)
-    return z[:, iu, ju]
+def dot_interaction(pooled: jax.Array, dense_v: jax.Array | None = None) -> jax.Array:
+    """DLRM's interaction: pairwise dots among the n feature vectors (the
+    bottom MLP's output [B, D], where there is one, and the pooled
+    embeddings [B, F, D]), led by that output as in arXiv:1906.00091's
+    ``interact_features`` -> top MLP input [B, (D +) n(n-1)/2]."""
+    with jax.named_scope("interaction"):
+        vectors = pooled
+        if dense_v is not None:
+            vectors = jnp.concatenate([dense_v[:, None, :], pooled], axis=1)
+        n = vectors.shape[1]
+        z = jnp.einsum("bnd,bmd->bnm", vectors, vectors)
+        iu, ju = jnp.triu_indices(n, k=1)
+        inter = z[:, iu, ju]
+        return inter if dense_v is None else jnp.concatenate([dense_v, inter], axis=-1)
 
 
 def apply_dense_given_pooled(params, batch, pooled, cfg: RecsysConfig) -> jax.Array:
     """G_d: DenseNet given pooled sparse embeddings [B, F, D] -> logit [B]."""
-    feats = [pooled]
-    if cfg.n_dense:
-        dense_v = apply_mlp(params["bottom_mlp"], batch["dense"].astype(cfg.dtype),
-                            final_activation="relu")
-        feats.insert(0, dense_v[:, None, :])
-    vectors = jnp.concatenate(feats, axis=1)  # [B, n_vec, D]
-    inter = dot_interaction(vectors)
-    top_in = jnp.concatenate([dense_v, inter], axis=-1) if cfg.n_dense else inter
-    return apply_mlp(params["top_mlp"], top_in)[:, 0]
+    with jax.named_scope("dense"):
+        dense_v = None
+        if cfg.n_dense:
+            dense_v = apply_mlp(params["bottom_mlp"], batch["dense"].astype(cfg.dtype),
+                                final_activation="relu")
+        top_in = dot_interaction(pooled, dense_v)
+        return apply_mlp(params["top_mlp"], top_in)[:, 0]
 
 
 def apply(params, batch, cfg: RecsysConfig) -> jax.Array:

@@ -136,25 +136,28 @@ def embedding_bag(params, ids: jax.Array, cfg: EmbeddingConfig) -> jax.Array:
 
 
 def embedding_bag_local(params, ids: jax.Array, cfg: EmbeddingConfig) -> jax.Array:
-    """Single-shard EmbeddingBag (jnp.take + masked pool)."""
+    """Single-shard EmbeddingBag (jnp.take + masked pool), under the
+    ``gather`` and ``pool`` scopes."""
     table = params["table"]
     B, F, P = ids.shape
     if F != cfg.num_features:
         raise ValueError(f"expected {cfg.num_features} features, got {F}")
-    mask = (ids >= 0).astype(table.dtype)[..., None]  # [B, F, P, 1]
 
-    if not cfg.qr_features:
-        rows = jnp.take(
-            table, _feature_row_index(cfg, ids).reshape(-1), axis=0
-        ).reshape(B, F, P, cfg.dim)
-    else:
-        rows = _gather_with_qr(table, ids, cfg)
+    with jax.named_scope("gather"):
+        if not cfg.qr_features:
+            rows = jnp.take(
+                table, _feature_row_index(cfg, ids).reshape(-1), axis=0
+            ).reshape(B, F, P, cfg.dim)
+        else:
+            rows = _gather_with_qr(table, ids, cfg)
 
-    pooled = (rows * mask).sum(axis=2)  # [B, F, dim]
-    if cfg.combine == "mean":
-        counts = jnp.maximum(mask.sum(axis=2), 1.0)
-        pooled = pooled / counts
-    return pooled
+    with jax.named_scope("pool"):
+        mask = (ids >= 0).astype(table.dtype)[..., None]  # [B, F, P, 1]
+        pooled = (rows * mask).sum(axis=2)  # [B, F, dim]
+        if cfg.combine == "mean":
+            counts = jnp.maximum(mask.sum(axis=2), 1.0)
+            pooled = pooled / counts
+        return pooled
 
 
 def _gather_with_qr(table, ids, cfg: EmbeddingConfig):
@@ -291,41 +294,42 @@ def embedding_bag_hot_cold(
 
     The caller adds them; keeping them separate mirrors the paper's pipeline
     where the hot partial sum is produced on the accelerator and the cold
-    partial sum (Psum) arrives from the host/sharded side.
+    partial sum (Psum) arrives from the host/sharded side.  Runs under the
+    same ``gather`` / ``pool`` scopes as ``embedding_bag_local``.
     """
     cfg = layout.cfg
     B, F, P = ids.shape
-    hot_rows = jnp.asarray(layout.hot_rows, jnp.int32)[None, :, None]
-    hot_off = jnp.asarray(layout.hot_offsets[:-1], jnp.int32)[None, :, None]
-    cold_off = jnp.asarray(layout.cold_offsets[:-1], jnp.int32)[None, :, None]
-
-    valid = ids >= 0
-    safe = jnp.maximum(ids, 0)
-    is_hot = valid & (safe < hot_rows)
-    is_cold = valid & ~(safe < hot_rows)
-
-    # masked slots index row 0 of the right table; clip because fully-hot
-    # (or fully-cold) features leave the other table's offset out of range
-    # (jnp.take's default OOB mode is 'fill' = NaN).
-    n_hot = max(layout.total_hot, 1)
-    n_cold = max(layout.total_cold, 1)
-    hot_idx = jnp.clip(jnp.where(is_hot, safe, 0) + hot_off, 0, n_hot - 1)
-    cold_idx = jnp.clip(jnp.where(is_cold, safe - hot_rows, 0) + cold_off, 0,
-                        n_cold - 1)
-
     dim = cfg.dim
-    if layout.total_hot:
-        hot_rows_g = jnp.take(split_params["hot"], hot_idx.reshape(-1), axis=0)
-        hot_psum = (
-            hot_rows_g.reshape(B, F, P, dim)
-            * is_hot[..., None].astype(hot_rows_g.dtype)
-        ).sum(axis=2)
-    else:
-        hot_psum = jnp.zeros((B, F, dim), split_params["cold"].dtype)
+    with jax.named_scope("gather"):
+        hot_rows = jnp.asarray(layout.hot_rows, jnp.int32)[None, :, None]
+        hot_off = jnp.asarray(layout.hot_offsets[:-1], jnp.int32)[None, :, None]
+        cold_off = jnp.asarray(layout.cold_offsets[:-1], jnp.int32)[None, :, None]
 
-    cold_rows_g = jnp.take(split_params["cold"], cold_idx.reshape(-1), axis=0)
-    cold_psum = (
-        cold_rows_g.reshape(B, F, P, dim)
-        * is_cold[..., None].astype(cold_rows_g.dtype)
-    ).sum(axis=2)
-    return hot_psum, cold_psum
+        valid = ids >= 0
+        safe = jnp.maximum(ids, 0)
+        is_hot = valid & (safe < hot_rows)
+        is_cold = valid & ~(safe < hot_rows)
+
+        # masked slots index row 0 of the right table; clip because fully-hot
+        # (or fully-cold) features leave the other table's offset out of range
+        # (jnp.take's default OOB mode is 'fill' = NaN).
+        n_hot = max(layout.total_hot, 1)
+        n_cold = max(layout.total_cold, 1)
+        hot_idx = jnp.clip(jnp.where(is_hot, safe, 0) + hot_off, 0, n_hot - 1)
+        cold_idx = jnp.clip(jnp.where(is_cold, safe - hot_rows, 0) + cold_off, 0,
+                            n_cold - 1)
+        if layout.total_hot:
+            hot_rows_g = jnp.take(split_params["hot"], hot_idx.reshape(-1),
+                                  axis=0).reshape(B, F, P, dim)
+        cold_rows_g = jnp.take(split_params["cold"], cold_idx.reshape(-1),
+                               axis=0).reshape(B, F, P, dim)
+
+    with jax.named_scope("pool"):
+        if layout.total_hot:
+            hot_psum = (hot_rows_g * is_hot[..., None].astype(hot_rows_g.dtype)
+                        ).sum(axis=2)
+        else:
+            hot_psum = jnp.zeros((B, F, dim), split_params["cold"].dtype)
+        cold_psum = (cold_rows_g * is_cold[..., None].astype(cold_rows_g.dtype)
+                     ).sum(axis=2)
+        return hot_psum, cold_psum

@@ -35,17 +35,19 @@ def init_mlp(key, sizes: Sequence[int], dtype=jnp.float32):
 
 
 def apply_mlp(params, x, *, final_activation=None):
-    """ReLU between layers; ``final_activation`` in {None,'relu','sigmoid'}."""
+    """ReLU between layers; ``final_activation`` in {None,'relu','sigmoid'}.
+    Runs under the ``mlp`` scope."""
     n = len(params)
-    for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
-        if i < n - 1:
-            x = jax.nn.relu(x)
-        elif final_activation == "relu":
-            x = jax.nn.relu(x)
-        elif final_activation == "sigmoid":
-            x = jax.nn.sigmoid(x)
-    return x
+    with jax.named_scope("mlp"):
+        for i, layer in enumerate(params):
+            x = x @ layer["w"] + layer["b"]
+            if i < n - 1:
+                x = jax.nn.relu(x)
+            elif final_activation == "relu":
+                x = jax.nn.relu(x)
+            elif final_activation == "sigmoid":
+                x = jax.nn.sigmoid(x)
+        return x
 
 
 # ---------------------------------------------------------------------------

@@ -43,26 +43,30 @@ def init(key, cfg: RecsysConfig):
 
 def apply_sparse(params, batch, cfg: RecsysConfig):
     """G_s: deep embeddings [B, F, D] and wide scalar sums [B, F, 1]."""
-    deep = emb_lib.embedding_bag(params["embedding"], batch["sparse_ids"], cfg.embedding)
-    wide = emb_lib.embedding_bag(params["wide"], batch["sparse_ids"], _wide_cfg(cfg))
-    return deep, wide
+    with jax.named_scope("sparse"):
+        deep = emb_lib.embedding_bag(params["embedding"], batch["sparse_ids"],
+                                     cfg.embedding)
+        wide = emb_lib.embedding_bag(params["wide"], batch["sparse_ids"], _wide_cfg(cfg))
+        return deep, wide
 
 
 def apply_dense_given_pooled(params, batch, pooled, cfg: RecsysConfig) -> jax.Array:
+    """G_d: wide logit plus the deep MLP and task towers -> logit(s)."""
     deep_emb, wide_emb = pooled
     B = deep_emb.shape[0]
-    deep_in = deep_emb.reshape(B, -1)
-    wide_logit = wide_emb.sum(axis=(1, 2))
-    if cfg.n_dense:
-        dense = batch["dense"].astype(cfg.dtype)
-        deep_in = jnp.concatenate([deep_in, dense], axis=-1)
-        wide_logit = wide_logit + dense @ params["wide_dense"]
-    hidden = apply_mlp(params["deep_mlp"], deep_in, final_activation="relu")
-    logits = jnp.stack(
-        [apply_mlp(t, hidden)[:, 0] for t in params["towers"]], axis=-1
-    )  # [B, n_tasks]
-    logits = logits + wide_logit[:, None]
-    return logits[:, 0] if cfg.n_tasks == 1 else logits
+    with jax.named_scope("dense"):
+        deep_in = deep_emb.reshape(B, -1)
+        wide_logit = wide_emb.sum(axis=(1, 2))
+        if cfg.n_dense:
+            dense = batch["dense"].astype(cfg.dtype)
+            deep_in = jnp.concatenate([deep_in, dense], axis=-1)
+            wide_logit = wide_logit + dense @ params["wide_dense"]
+        hidden = apply_mlp(params["deep_mlp"], deep_in, final_activation="relu")
+        logits = jnp.stack(
+            [apply_mlp(t, hidden)[:, 0] for t in params["towers"]], axis=-1
+        )  # [B, n_tasks]
+        logits = logits + wide_logit[:, None]
+        return logits[:, 0] if cfg.n_tasks == 1 else logits
 
 
 def apply(params, batch, cfg: RecsysConfig) -> jax.Array:

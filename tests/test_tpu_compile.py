@@ -8,6 +8,8 @@ worker collects the same tests and only the one given this file loads the
 TPU library.
 """
 import os
+import pathlib
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -91,3 +93,10 @@ def test_rmc1_serve_step_compiles_for_v5e(one_chip):
         params, batch).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > cfg.embedding.bytes()
+    # each layer keeps its named scope through the compiler's fusions, so a
+    # device trace can be read by them (benchmarks/chip/chipbench/scopes.py)
+    sys.path.insert(0, str(pathlib.Path(__file__).parents[1] / "benchmarks" / "chip"))
+    from chipbench.scopes import op_scopes
+
+    paths = set(op_scopes(compiled.as_text()).values())
+    assert {"sparse/gather", "sparse/pool", "dense/mlp", "dense/interaction"} <= paths
