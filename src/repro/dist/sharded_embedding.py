@@ -16,7 +16,6 @@ in ``tests/test_distributed.py`` exercise.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import logical
@@ -26,22 +25,26 @@ from repro.models import embedding as emb_lib
 def sharded_row_gather(table, ids, axis_name=None):
     """Row gather from a (possibly) row-sharded table.
 
-    table: [rows, dim] annotated sharded over the model axis; ids: any int
-    shape.  ``axis_name`` pins the table to an explicit mesh axis instead
-    of the bound logical "model" axis (None = use the active binding; no
-    binding = plain local gather).  Returns ``ids.shape + (dim,)``.
+    table: [rows, dim] (or a ``LineTable``, sharded by lines) annotated
+    sharded over the model axis; ids: any int shape.  ``axis_name`` pins
+    the table to an explicit mesh axis instead of the bound logical "model"
+    axis (None = use the active binding; no binding = plain local gather).
+    Returns ``ids.shape + (dim,)``.
     """
     if axis_name is not None:
         mesh = logical.current_mesh()
         if mesh is not None:
-            table = jax.lax.with_sharding_constraint(
-                table, NamedSharding(mesh, P(axis_name, None))
-            )
-        return jnp.take(table, ids, axis=0)
-    if logical.model_axis_name() is None:
-        return jnp.take(table, ids, axis=0)
-    table = logical.constrain(table, ("model", None))
-    return jnp.take(table, ids, axis=0)
+            table = jax.tree.map(lambda t: jax.lax.with_sharding_constraint(
+                t, NamedSharding(mesh, P(axis_name, None))), table)
+        return emb_lib.gather_rows(table, ids)
+    if logical.model_axis_name() is not None:
+        table = _row_sharded(table)
+    return emb_lib.gather_rows(table, ids)
+
+
+def _row_sharded(table):
+    """The table (its rows, or a ``LineTable``'s lines) pinned row-sharded."""
+    return jax.tree.map(lambda t: logical.constrain(t, ("model", None)), table)
 
 
 def embedding_bag_sharded(params, ids, cfg):
@@ -52,6 +55,6 @@ def embedding_bag_sharded(params, ids, cfg):
     table pinned row-sharded and the pooled output pinned batch-sharded.
     ids: [B, F, P] int32, -1-padded -> [B, F, dim].
     """
-    table = logical.constrain(params["table"], ("model", None))
-    pooled = emb_lib.embedding_bag_local({"table": table}, ids, cfg)
+    pooled = emb_lib.embedding_bag_local({"table": _row_sharded(params["table"])},
+                                         ids, cfg)
     return logical.constrain(pooled, ("batch", None, None))

@@ -2,15 +2,21 @@
 
 JAX has no native ``nn.EmbeddingBag`` and no CSR sparse — the multi-hot
 gather+pool that dominates recommendation inference (the paper's SparseNet)
-is built here from ``jnp.take`` + masked reduction / ``jax.ops.segment_sum``.
+is built here from row gathers + masked reduction / ``jax.ops.segment_sum``.
 This module is single-device semantics; the distributed (model-axis sharded)
 lookup lives in ``repro.dist.sharded_embedding`` and the fused TPU kernel in
 ``repro.kernels.embedding_bag``.
 
 Layout: all feature tables are concatenated row-wise into ONE combined
-``[total_rows, dim]`` array (FBGEMM table-batched-embedding style); feature
+``[total_rows, dim]`` table (FBGEMM table-batched-embedding style); feature
 ``f``'s ids are shifted by ``row_offsets[f]``. This gives a single gather for
 the whole SparseNet and a single row-sharded array for the model axis.
+
+Storage: 32-bit rows of 8 to 64 lanes are stored ``rows_per_line`` to a
+128-lane line (a ``LineTable``). The TPU lays a ``[R, 32]`` f32 array out
+rows-minor, so a row's floats are not contiguous and its gather crawls; a
+``[R // 4, 128]`` array keeps rows major, and one index fetches 512
+contiguous bytes. Every reader fetches rows through ``gather_rows``.
 
 Hot/cold split (paper §IV-B, locality-aware partition): ids are assumed
 frequency-ranked per table (the synthetic data generator produces them that
@@ -29,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.init import embedding_init
+
+LANES = 128  # lanes of a TPU vector register: the width of a stored line
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,8 +58,10 @@ class EmbeddingConfig:
     qr_features: tuple[int, ...] = ()
     qr_buckets: int = 65536
     dtype: Any = jnp.float32
-    # combined table rows are padded to a multiple of this so the row-wise
-    # model-axis shard is always even (512 covers every production mesh).
+    # the stored table's leading dimension (rows, or lines of
+    # ``rows_per_line`` rows) is padded to a multiple of this so the
+    # row-wise model-axis shard is always even (512 covers every
+    # production mesh).
     row_pad: int = 512
 
     def __post_init__(self):
@@ -79,9 +89,25 @@ class EmbeddingConfig:
         return np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
 
     @property
+    def rows_per_line(self) -> int:
+        """Rows stored side by side in one 128-lane line.
+
+        ``128 // dim`` for 32-bit rows of 8 to 64 lanes that divide 128
+        (4 for dim 32, 2 for dim 64); 1 otherwise, where the table stays
+        ``[rows, dim]``: wider rows fill a line already, 16-bit rows are
+        laid out otherwise, and a line of narrower ones (a wide part's dim
+        1) would read over 16 rows for the one looked up.
+        """
+        if (jnp.dtype(self.dtype).itemsize == 4 and 8 <= self.dim < LANES
+                and LANES % self.dim == 0):
+            return LANES // self.dim
+        return 1
+
+    @property
     def total_rows(self) -> int:
         raw = int(self.row_offsets[-1])
-        return -(-raw // self.row_pad) * self.row_pad
+        pad = self.row_pad * self.rows_per_line
+        return -(-raw // pad) * pad
 
     @property
     def max_pooling(self) -> int:
@@ -91,8 +117,63 @@ class EmbeddingConfig:
         return self.total_rows * self.dim * dtype_bytes
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class LineTable:
+    """A ``[rows, dim]`` table stored ``k = 128 // dim`` rows to a line.
+
+    ``lines`` is ``[rows // k, 128]``: line ``i`` holds rows ``k*i`` to
+    ``k*i + k - 1`` side by side, the row-major reshape of the rows. The
+    values are the rows' own; ``np.asarray`` gives them as ``[rows, dim]``.
+    """
+
+    lines: jax.Array
+    dim: int = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def rows_per_line(self) -> int:
+        return LANES // self.dim
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        return (self.lines.shape[0] * self.rows_per_line, self.dim)
+
+    @property
+    def dtype(self):
+        return self.lines.dtype
+
+    def rows(self) -> jax.Array:
+        """The ``[rows, dim]`` table (a relayout: keep it out of steps)."""
+        return self.lines.reshape(self.shape)
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.lines, dtype).reshape(self.shape)
+
+
+def _gather_lines(table: LineTable, row_ids: jax.Array) -> jax.Array:
+    """The lines holding rows ``row_ids`` -> ``row_ids.shape + (128,)``.
+    The ids must lie in the table: nothing is filled or clamped."""
+    return table.lines.at[row_ids // table.rows_per_line].get(
+        mode="promise_in_bounds")
+
+
+def gather_rows(table, row_ids: jax.Array) -> jax.Array:
+    """Rows ``row_ids`` (any int shape) of a ``[rows, dim]`` array or a
+    ``LineTable`` -> ``row_ids.shape + (dim,)``.
+
+    A ``LineTable``'s row is picked from its line by a lane mask (a sum of
+    the row with exact zeros), so the values are the stored ones."""
+    if not isinstance(table, LineTable):
+        return jnp.take(table, row_ids, axis=0)
+    k, dim = table.rows_per_line, table.dim
+    blocks = _gather_lines(table, row_ids).reshape(*row_ids.shape, k, dim)
+    pick = (row_ids % k)[..., None, None] == jnp.arange(k)[:, None]
+    return jnp.where(pick, blocks, 0).sum(axis=-2)
+
+
 def init_embedding(key, cfg: EmbeddingConfig):
-    """One combined [total_rows, dim] table, DLRM uniform init per table."""
+    """One combined [total_rows, dim] table, DLRM uniform init per table,
+    stored as a ``LineTable`` where ``cfg.rows_per_line > 1``."""
     # Init the whole combined table in one draw with a per-table scale:
     # equivalent in distribution to per-table U(-1/sqrt(V), 1/sqrt(V)).
     table = jax.random.uniform(
@@ -103,7 +184,10 @@ def init_embedding(key, cfg: EmbeddingConfig):
     for f in range(cfg.num_features):
         v = cfg.vocab_sizes[f]
         scales[offsets[f] : offsets[f + 1]] = 1.0 / np.sqrt(v)
-    return {"table": (table * jnp.asarray(scales)).astype(cfg.dtype)}
+    table = (table * jnp.asarray(scales)).astype(cfg.dtype)
+    if cfg.rows_per_line > 1:
+        return {"table": LineTable(table.reshape(-1, LANES), cfg.dim)}
+    return {"table": table}
 
 
 def _feature_row_index(cfg: EmbeddingConfig, ids: jax.Array) -> jax.Array:
@@ -136,17 +220,19 @@ def embedding_bag(params, ids: jax.Array, cfg: EmbeddingConfig) -> jax.Array:
 
 
 def embedding_bag_local(params, ids: jax.Array, cfg: EmbeddingConfig) -> jax.Array:
-    """Single-shard EmbeddingBag (jnp.take + masked pool), under the
+    """Single-shard EmbeddingBag (row gather + masked pool), under the
     ``gather`` and ``pool`` scopes."""
     table = params["table"]
     B, F, P = ids.shape
     if F != cfg.num_features:
         raise ValueError(f"expected {cfg.num_features} features, got {F}")
+    if isinstance(table, LineTable) and not cfg.qr_features:
+        return _embedding_bag_lines(table, ids, cfg)
 
     with jax.named_scope("gather"):
         if not cfg.qr_features:
-            rows = jnp.take(
-                table, _feature_row_index(cfg, ids).reshape(-1), axis=0
+            rows = gather_rows(
+                table, _feature_row_index(cfg, ids).reshape(-1)
             ).reshape(B, F, P, cfg.dim)
         else:
             rows = _gather_with_qr(table, ids, cfg)
@@ -157,6 +243,32 @@ def embedding_bag_local(params, ids: jax.Array, cfg: EmbeddingConfig) -> jax.Arr
         if cfg.combine == "mean":
             counts = jnp.maximum(mask.sum(axis=2), 1.0)
             pooled = pooled / counts
+        return pooled
+
+
+def _embedding_bag_lines(table: LineTable, ids: jax.Array,
+                         cfg: EmbeddingConfig) -> jax.Array:
+    """EmbeddingBag over a ``LineTable``: gather whole lines, then pool.
+
+    The pool keeps lane ``l`` of a looked-up line where the row sits at
+    lane block ``l // dim`` (and the id is not padding), sums the bag's
+    lines, and folds the ``k`` lane blocks: only exact zeros are added to
+    the rows' values, all on the vector unit (no matrix product).
+    """
+    B, F, P = ids.shape
+    k, dim = table.rows_per_line, cfg.dim
+    with jax.named_scope("gather"):
+        row = _feature_row_index(cfg, ids)
+        lines = _gather_lines(table, row)  # [B, F, P, 128]
+
+    with jax.named_scope("pool"):
+        valid = ids >= 0
+        keep = ((row % k)[..., None] == jnp.arange(LANES) // dim) & valid[..., None]
+        pooled = jnp.where(keep, lines, 0).sum(axis=2)  # [B, F, 128]
+        pooled = pooled.reshape(B, F, k, dim).sum(axis=2)  # [B, F, dim]
+        if cfg.combine == "mean":
+            counts = jnp.maximum(valid.astype(table.dtype).sum(axis=2), 1.0)
+            pooled = pooled / counts[..., None]
         return pooled
 
 
@@ -176,11 +288,11 @@ def _gather_with_qr(table, ids, cfg: EmbeddingConfig):
         base = int(offsets[f])
         if f in cfg.qr_features:
             q_rows = -(-cfg.vocab_sizes[f] // cfg.qr_buckets)
-            quot = jnp.take(table, base + fid // cfg.qr_buckets, axis=0)
-            rem = jnp.take(table, base + q_rows + fid % cfg.qr_buckets, axis=0)
+            quot = gather_rows(table, base + fid // cfg.qr_buckets)
+            rem = gather_rows(table, base + q_rows + fid % cfg.qr_buckets)
             per_feature.append(quot * rem)
         else:
-            per_feature.append(jnp.take(table, base + fid, axis=0))
+            per_feature.append(gather_rows(table, base + fid))
     return jnp.stack(per_feature, axis=1)  # [B, F, P, dim]
 
 
@@ -272,9 +384,12 @@ def make_hot_cold_layout(
 
 
 def split_hot_cold(params, layout: HotColdLayout):
-    """Re-lay the combined table into {hot, cold} per the layout."""
+    """Re-lay the combined table into {hot, cold} ``[rows, dim]`` tables per
+    the layout (a ``LineTable`` is unpacked here, once, outside any step)."""
     cfg = layout.cfg
     table = params["table"]
+    if isinstance(table, LineTable):
+        table = table.rows()
     hots, colds = [], []
     off = cfg.row_offsets
     for f in range(cfg.num_features):

@@ -18,6 +18,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.models.embedding import LineTable
+
 
 @dataclasses.dataclass(frozen=True)
 class Optimizer:
@@ -77,30 +79,44 @@ def rowwise_adagrad(lr: float = 0.01, eps: float = 1e-8,
                     embedding_keys: tuple[str, ...] = ("table", "hot", "cold"),
                     ) -> Optimizer:
     """AdaGrad with row-wise accumulators for 2-D embedding tables (one
-    scalar per row) and full accumulators elsewhere."""
+    scalar per row, a ``LineTable``'s rows included) and full accumulators
+    elsewhere."""
 
-    def _is_embedding(path) -> bool:
-        return any(getattr(k, "key", None) in embedding_keys for k in path)
+    def _is_embedding(path, p) -> bool:
+        return isinstance(p, LineTable) or (
+            any(getattr(k, "key", None) in embedding_keys for k in path)
+            and p.ndim == 2)
+
+    def _rows(p):
+        return p.rows() if isinstance(p, LineTable) else p
+
+    def _is_table(x) -> bool:
+        return isinstance(x, LineTable)
 
     def init(params):
         def acc(path, p):
-            if _is_embedding(path) and p.ndim == 2:
+            if _is_embedding(path, p):
                 return jnp.zeros((p.shape[0], 1), jnp.float32)
             return jnp.zeros_like(p, jnp.float32)
-        return {"acc": jax.tree_util.tree_map_with_path(acc, params)}
+        return {"acc": jax.tree_util.tree_map_with_path(acc, params,
+                                                        is_leaf=_is_table)}
 
     def update(params, grads, state):
         def upd(path, p, g, a):
-            g32 = g.astype(jnp.float32)
-            if _is_embedding(path) and p.ndim == 2:
+            g32 = _rows(g).astype(jnp.float32)
+            if _is_embedding(path, p):
                 a_new = a + jnp.mean(jnp.square(g32), axis=1, keepdims=True)
             else:
                 a_new = a + jnp.square(g32)
-            p_new = p.astype(jnp.float32) - lr * g32 / (jnp.sqrt(a_new) + eps)
-            return p_new.astype(p.dtype), a_new
+            p_new = _rows(p).astype(jnp.float32) - lr * g32 / (jnp.sqrt(a_new) + eps)
+            p_new = p_new.astype(p.dtype)
+            if isinstance(p, LineTable):
+                p_new = LineTable(p_new.reshape(p.lines.shape), p.dim)
+            return p_new, a_new
 
         flat = jax.tree_util.tree_map_with_path(
-            lambda path, p, g, a: upd(path, p, g, a), params, grads, state["acc"]
+            lambda path, p, g, a: upd(path, p, g, a), params, grads, state["acc"],
+            is_leaf=_is_table,
         )
         params = jax.tree.map(lambda t: t[0], flat,
                               is_leaf=lambda x: isinstance(x, tuple))

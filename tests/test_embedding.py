@@ -25,13 +25,16 @@ except ImportError:  # dev-only dep (requirements-dev.txt): skip ONLY the
 from repro.models.embedding import (
     EmbeddingConfig,
     HotColdLayout,
+    LineTable,
     embedding_bag_hot_cold,
     embedding_bag_local,
     embedding_bag_ragged,
+    gather_rows,
     init_embedding,
     make_hot_cold_layout,
     split_hot_cold,
 )
+from repro.train.optimizer import rowwise_adagrad
 
 
 def _cfg(vocabs=(50, 100, 30), dim=8, pooling=(4, 2, 1), **kw):
@@ -157,3 +160,102 @@ def test_grad_only_touches_looked_up_rows():
     gt = np.asarray(g["table"])
     touched = set(np.nonzero(np.abs(gt).sum(1))[0].tolist())
     assert touched == {3, 5}
+
+
+# ---------------------------------------------------------------------------
+# Packed storage: rows_per_line rows to a 128-lane line
+# ---------------------------------------------------------------------------
+
+
+def _lattice(shape, seed):
+    """Values on a 2**-6 grid in [-1, 1]: every bag sum below is exact in
+    float32 in any order, so packed and row storage must agree bit for bit
+    (the packed pool adds the same values in another order)."""
+    r = np.random.default_rng(seed)
+    return (r.integers(-64, 65, shape) / 64).astype(np.float32)
+
+
+def _edge_ids(cfg, batch, seed):
+    """-1-padded ids with the first and last row of every feature."""
+    r = np.random.default_rng(seed)
+    P = cfg.max_pooling
+    ids = np.stack([r.integers(-1, v, (batch, P)) for v in cfg.vocab_sizes], axis=1)
+    for f, v in enumerate(cfg.vocab_sizes):
+        ids[0, f, :2] = (0, v - 1)
+        ids[1, f, :] = (v - 1, -1, 0, -1)[:P] if P >= 4 else v - 1
+    return jnp.asarray(ids.astype(np.int32))
+
+
+@pytest.mark.parametrize("qr", [False, True], ids=["plain", "qr"])
+@pytest.mark.parametrize("combine", ["sum", "mean"])
+@pytest.mark.parametrize("dim", [8, 16, 32, 64])
+def test_packed_storage_pools_as_rows(dim, combine, qr):
+    cfg = EmbeddingConfig(vocab_sizes=(50, 300, 7), dim=dim, pooling=(4, 3, 2),
+                          combine=combine, row_pad=8, qr_buckets=16,
+                          qr_features=(1,) if qr else ())
+    k = 128 // dim
+    assert cfg.rows_per_line == k and cfg.total_rows % (8 * k) == 0
+    t = _lattice((cfg.total_rows, dim), dim)
+    packed = {"table": LineTable(jnp.asarray(t.reshape(-1, 128)), dim)}
+    ids = _edge_ids(cfg, 6, dim)
+    got = embedding_bag_local(packed, ids, cfg)
+    want = embedding_bag_local({"table": jnp.asarray(t)}, ids, cfg)
+    np.testing.assert_array_equal(got, want)
+    if not qr and combine == "sum":  # the oracle divides in float64
+        np.testing.assert_array_equal(got, _ref_bag(t, np.asarray(ids), cfg))
+
+
+@pytest.mark.parametrize("dim", [8, 16, 32, 64])
+def test_packed_init_holds_the_row_draw(dim):
+    """init packs the same [rows, dim] values, and gather_rows reads them back."""
+    cfg = _cfg(vocabs=(50, 70), dim=dim, pooling=(2, 2))
+    key = jax.random.PRNGKey(dim)
+    table = init_embedding(key, cfg)["table"]
+    assert isinstance(table, LineTable)
+    assert table.lines.shape == (cfg.total_rows // cfg.rows_per_line, 128)
+    assert table.shape == (cfg.total_rows, dim)
+    draw = np.asarray(jax.random.uniform(key, (cfg.total_rows, dim), minval=-1.0,
+                                         maxval=1.0))
+    scale = np.ones((cfg.total_rows, 1), np.float32)
+    for f, v in enumerate(cfg.vocab_sizes):
+        scale[cfg.row_offsets[f]:cfg.row_offsets[f + 1]] = 1.0 / np.sqrt(v)
+    rows = np.asarray(table)
+    np.testing.assert_array_equal(rows, draw * scale)
+    np.testing.assert_array_equal(np.asarray(table.lines).reshape(rows.shape), rows)
+    ids = jnp.asarray([[0, 1, 49], [50, 119, cfg.total_rows - 1]], jnp.int32)
+    np.testing.assert_array_equal(gather_rows(table, ids), rows[np.asarray(ids)])
+
+
+@pytest.mark.parametrize("dim,dtype", [(18, jnp.float32), (1, jnp.float32),
+                                       (128, jnp.float32), (32, jnp.bfloat16)],
+                         ids=["dim18", "dim1", "dim128", "bf16"])
+def test_unpackable_tables_keep_rows(dim, dtype):
+    cfg = _cfg(dim=dim, dtype=dtype)
+    assert cfg.rows_per_line == 1
+    table = init_embedding(jax.random.PRNGKey(0), cfg)["table"]
+    assert isinstance(table, jax.Array)
+    assert table.shape == (cfg.total_rows, dim) and table.dtype == dtype
+    ids = jnp.asarray([3, 0, 51], jnp.int32)
+    np.testing.assert_array_equal(gather_rows(table, ids), np.asarray(table)[[3, 0, 51]])
+
+
+def test_rowwise_adagrad_on_packed_rows_matches_rows():
+    """One accumulator per row whether the table is packed or not."""
+    cfg = _cfg(vocabs=(20,), dim=32, pooling=(3,))
+    packed = init_embedding(jax.random.PRNGKey(0), cfg)
+    rows = {"table": jnp.asarray(np.asarray(packed["table"]))}
+    ids = jnp.asarray([[[3, 5, 5]], [[7, -1, 4]]], jnp.int32)
+    opt = rowwise_adagrad(lr=0.1)
+
+    def step(p):
+        g = jax.grad(lambda p: (embedding_bag_local(p, ids, cfg) ** 2).sum())(p)
+        return opt.update(p, g, opt.init(p))
+
+    p_packed, s_packed = step(packed)
+    p_rows, s_rows = step(rows)
+    assert isinstance(p_packed["table"], LineTable)
+    assert s_packed["acc"]["table"].shape == (cfg.total_rows, 1)
+    np.testing.assert_allclose(np.asarray(p_packed["table"]), p_rows["table"],
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(s_packed["acc"]["table"], s_rows["acc"]["table"],
+                               rtol=1e-6, atol=1e-9)

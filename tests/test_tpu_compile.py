@@ -7,8 +7,10 @@ topology is described inside a fixture, never at import, so every xdist
 worker collects the same tests and only the one given this file loads the
 TPU library.
 """
+import math
 import os
 import pathlib
+import re
 import sys
 
 import jax
@@ -83,8 +85,16 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     _assert_kernel(compiled)
 
 
+def _entry(hlo: str) -> list[str]:
+    """The entry computation's instruction lines."""
+    body = hlo[hlo.index("\nENTRY "):]
+    return body[: body.index("\n}")].splitlines()[1:]
+
+
 def test_rmc1_serve_step_compiles_for_v5e(one_chip):
     cfg = rmc1(prod=False)
+    # 32-wide f32 rows are stored four to a 128-lane line
+    assert cfg.embedding.rows_per_line == 4
     params = jax.eval_shape(lambda: dlrm.init(jax.random.PRNGKey(0), cfg))
     params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), params)
     batch = {k: _spec(s.shape, s.dtype, one_chip)
@@ -98,5 +108,23 @@ def test_rmc1_serve_step_compiles_for_v5e(one_chip):
     sys.path.insert(0, str(pathlib.Path(__file__).parents[1] / "benchmarks" / "chip"))
     from chipbench.scopes import op_scopes
 
-    paths = set(op_scopes(compiled.as_text()).values())
+    hlo = compiled.as_text()
+    paths = set(op_scopes(hlo).values())
     assert {"sparse/gather", "sparse/pool", "dense/mlp", "dense/interaction"} <= paths
+
+    # the table stays rows-major, and each lookup fetches one whole line
+    entry = _entry(hlo)
+    table = [ln for ln in entry if "parameter(" in ln and "embedding" in ln]
+    assert len(table) == 1 and "= f32[2500096,128]{1,0:T(8,128)} parameter(" in table[0]
+    gathers = [ln for ln in hlo.splitlines()
+               if " gather(" in ln and "sparse/gather" in ln]
+    assert gathers and all("slice_sizes={1,128}" in ln for ln in gathers)
+    # no instruction moves the table or the gathered [819200, 128] block:
+    # every copy, transpose or relayout is smaller than the block
+    block = 1024 * 10 * 80 * 128
+    moves = [re.search(r"= \(?\w+\[([\d,]*)\][^ ]* "
+                       r"(copy|copy-start|transpose|reshape)\(", ln) for ln in entry]
+    moves = [m for m in moves if m]
+    assert moves  # the pattern reads this compiler's text
+    for m in moves:
+        assert math.prod(int(d) for d in m.group(1).split(",") if d) < block, m.string[:200]
