@@ -7,6 +7,31 @@ search, the weights and the jitted forward; the benchmark supplies the
 inputs, the serving loop (the program has no request-taking server yet),
 the spans, the trace reduction and the reference check.
 
+What differs between model families is found by the configuration file's
+``family`` key, in two modules of that name:
+
+- ``chipbench/families/<family>.py``: ``INPUTS``, the step's input arrays
+  by name, each with its value in a launch's padded slots; ``IDS``, those
+  that hold ids (the ``sparse_dropped`` fault empties them);
+  ``TRAFFIC_KEYS``, the traffic parameters its pool reads beyond
+  ``traffic.Distributions`` (any other is refused);
+  ``program_config(cfg)``, the program's configuration, checked against
+  the file; ``program(pcfg)``, the program's ``(init(key),
+  apply(params, batch))``; ``make_pool(seed, n, cfg, dist)``, ``n`` items
+  (``traffic.Pool``) drawn from the seed;
+- ``reference/<family>.py``: ``init(seed, cfg)``, the reference's own
+  weights; ``forward_fn(cfg, precision)(params, batch)``, its scores
+  (``precision`` the file's ``check.reference_precision``, or
+  ``"bfloat16"`` for the control); ``work(cfg, items, valid_lookups,
+  launches)``, the least operations and bytes of the window's work, read
+  only by the readers that call ``readings.work``: ``flops``, the whole
+  forward's operations (``step_mfu``), and whatever a family's roofline
+  readers read (DLRM's ``sparse_bytes``, ``dense_flops``,
+  ``dense_bytes``).
+
+The configuration file also names the program's scopes (``scopes``) and
+the source files of its layers (``layers``), by which the trace is read.
+
 The serving loop mirrors ``examples/serve_recsys.py::serve``: a query's
 items are split into launches of ``d`` (the schedule's fused batch), the
 last one padded, and each launch is put on the device, run and waited on
@@ -19,6 +44,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import importlib
 import importlib.util
 import json
 import math
@@ -30,7 +56,7 @@ import time
 import jax
 import numpy as np
 
-from chipbench import readings, traffic, tracing
+from chipbench import readings, scopes, traffic, tracing
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parents[1]
@@ -41,6 +67,7 @@ POOL_LAUNCHES = 16
 SAMPLE_ITEMS = 16384    # items compared with the reference per run
 DRAIN_S = 60.0          # queries not done this long after the window are failed
 FAULTS = ("answer_altered", "half_batch", "sparse_dropped", "control_bfloat16")
+STEP_MODULE = "jit_serve_step"  # the compiled step's program in the trace
 
 
 def load_json(path: pathlib.Path) -> dict:
@@ -76,6 +103,16 @@ def resolve(workload: str, bench_file: pathlib.Path = ROOT / "BENCHMARK.json") -
     layer = [m for m in spec["per_layer"]
              if applies(m) or (applies(m) is None and m["moves"] in names)]
     return Cell(workload, w["chips"], cfg, mix, e2e, layer)
+
+
+def family(cfg: dict):
+    """The configuration's family module, ``chipbench/families/<family>.py``."""
+    return importlib.import_module(f"chipbench.families.{cfg['family']}")
+
+
+def reference(cfg: dict):
+    """The configuration's plain reference, ``reference/<family>.py``."""
+    return importlib.import_module(f"reference.{cfg['family']}")
 
 
 def reader(metric: str):
@@ -121,15 +158,26 @@ class Launch:
     seconds: float
 
 
+def padded(inputs: dict, arrays: dict[str, np.ndarray], d: int) -> dict[str, np.ndarray]:
+    """A launch's batch of ``d`` slots: each input's rows, then its fill."""
+    out = {}
+    for k, fill in inputs.items():
+        a = arrays[k]
+        out[k] = np.full((d,) + a.shape[1:], fill, a.dtype)
+        out[k][:len(a)] = a
+    return out
+
+
 class Server:
-    """Fused launches of ``d`` pool items through the program's step."""
+    """Fused launches of ``d`` pool items through the program's step; the
+    family module (``fam``) names the step's inputs and their fill."""
 
     def __init__(self, step, params, pool: traffic.Pool, d: int, spans: Spans,
-                 fault: str | None = None):
+                 fam, fault: str | None = None):
         self.step, self.params, self.pool, self.d = step, params, pool, d
-        self.spans, self.fault = spans, fault
-        self.dense = np.zeros((d, pool.dense.shape[1]), np.float32)
-        self.ids = np.full((d,) + pool.ids.shape[1:], -1, np.int32)
+        self.spans, self.fam, self.fault = spans, fam, fault
+        # the launch's buffers, all fill until a launch copies items in
+        self.buf = padded(fam.INPUTS, {k: a[:0] for k, a in pool.arrays.items()}, d)
         self.launches: list[Launch] = []
         self.valid_lookups = 0
         self.items = 0
@@ -138,23 +186,23 @@ class Server:
         N = len(self.pool)
         s = start % N
         first = min(n, N - s)
-        self.dense[:first] = self.pool.dense[s:s + first]
-        self.ids[:first] = self.pool.ids[s:s + first]
-        if first < n:
-            self.dense[first:n] = self.pool.dense[:n - first]
-            self.ids[first:n] = self.pool.ids[:n - first]
-        if n < self.d:
-            self.dense[n:] = 0.0
-            self.ids[n:] = -1
+        for k, fill in self.fam.INPUTS.items():
+            buf, src = self.buf[k], self.pool.arrays[k]
+            buf[:first] = src[s:s + first]
+            if first < n:
+                buf[first:n] = src[:n - first]
+            if n < self.d:
+                buf[n:] = fill
         if self.fault == "sparse_dropped":
-            self.ids[:] = -1
+            for k in self.fam.IDS:
+                self.buf[k][:] = self.fam.INPUTS[k]
 
     def launch(self, start: int, n: int) -> np.ndarray:
         span = self.spans
         t0 = time.perf_counter()
         with span("assemble"):
             self._fill(start, n)
-            batch = {"dense": self.dense, "sparse_ids": self.ids}
+            batch = dict(self.buf)
         with span("device_put"):
             batch = jax.device_put(batch)
         with span("dispatch"):
@@ -253,24 +301,19 @@ def check(cell: Cell, seed: int, server: Server, picked: list[int],
           precision: str | None = None) -> dict:
     """Reference scores of the picked launches' items, and the widest gap
     |program - reference| / (1 + |reference|) over them."""
-    import jax.numpy as jnp
-
-    from reference import dlrm as ref
-
     cfg = cell.cfg
+    ref = reference(cfg)
     precision = precision or cfg["check"]["reference_precision"]
     params = ref.init(seed, cfg)
     fwd = ref.forward_fn(cfg, precision)
+    inputs, arrays = server.fam.INPUTS, server.pool.arrays
     N, d = len(server.pool), server.d
     got, want = [], []
     for i in picked:
         la = server.launches[i]
         idx = (la.start + np.arange(la.n)) % N
-        dense = np.zeros((d, server.dense.shape[1]), np.float32)
-        ids = np.full((d,) + server.ids.shape[1:], -1, np.int32)
-        dense[:la.n] = server.pool.dense[idx]
-        ids[:la.n] = server.pool.ids[idx]
-        want.append(np.asarray(fwd(params, jnp.asarray(dense), jnp.asarray(ids)))[:la.n])
+        batch = padded(inputs, {k: arrays[k][idx] for k in inputs}, d)
+        want.append(np.asarray(fwd(params, batch))[:la.n])
         got.append(la.scores)
     got = np.concatenate(got).astype(np.float64)
     want = np.concatenate(want).astype(np.float64)
@@ -283,28 +326,6 @@ def check(cell: Cell, seed: int, server: Server, picked: list[int],
 # ---------------------------------------------------------------------------
 # The program under test
 # ---------------------------------------------------------------------------
-
-
-def program_config(cfg: dict):
-    """The program's configuration for this file, checked against its sizes."""
-    from repro.configs.paper_models import PAPER_MODELS
-
-    prog = cfg["program"]
-    pcfg = PAPER_MODELS[prog["model"]](prod=prog["prod"])
-    emb = pcfg.embedding
-    have = {
-        "num_tables": emb.num_features,
-        "rows_per_table": emb.vocab_sizes[0] if len(set(emb.vocab_sizes)) == 1 else None,
-        "embedding_dim": emb.dim,
-        "pooling": emb.max_pooling if len(set(emb.pooling)) == 1 else None,
-        "num_dense": pcfg.n_dense,
-        "bottom_mlp": list(pcfg.bottom_mlp),
-        "top_mlp": list(pcfg.top_mlp),
-    }
-    diff = {k: (v, cfg[k]) for k, v in have.items() if v != cfg[k]}
-    if diff or emb.row_pad != cfg["weights"]["row_pad"]:
-        raise SystemExit(f"program configuration differs from {prog['model']}'s file: {diff}")
-    return pcfg
 
 
 def schedule(cfg: dict, pcfg) -> tuple[dict, float]:
@@ -324,19 +345,16 @@ def schedule(cfg: dict, pcfg) -> tuple[dict, float]:
              "m": int(res.sched.m), "o": int(res.sched.o)}, search_s)
 
 
-def build(pcfg, seed: int, d: int, pool: traffic.Pool):
+def build(fam, pcfg, seed: int, d: int, pool: traffic.Pool):
     """Weights from the seed in one jitted call, and the step compiled at
     its one shape [d, ...]."""
-    from repro.launch.steps import RECSYS_APPLY, RECSYS_INIT
-
-    init = RECSYS_INIT[pcfg.interaction]
-    params = jax.jit(lambda k: init(k, pcfg))(jax.random.PRNGKey(seed % 2**32))
-    apply = RECSYS_APPLY[pcfg.interaction]
+    init, apply = fam.program(pcfg)
+    params = jax.jit(init)(jax.random.PRNGKey(seed % 2**32))
 
     def serve_step(p, b):
-        return apply(p, b, pcfg)
+        return apply(p, b)
 
-    batch = {"dense": pool.dense[:d], "sparse_ids": pool.ids[:d]}
+    batch = {k: pool.arrays[k][:d] for k in fam.INPUTS}
     return params, jax.jit(serve_step).lower(params, batch).compile()
 
 
@@ -413,23 +431,23 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     compile_cache()
-    pcfg = program_config(cell.cfg)
+    fam = family(cell.cfg)
+    pcfg = fam.program_config(cell.cfg)
     sched, search_s = schedule(cell.cfg, pcfg)
     d = sched["d"]
-    dist = traffic.Distributions.from_mix(cell.mix)
-    pool = traffic.make_pool(seed, POOL_LAUNCHES * d, cell.cfg, dist)
-    params, step = build(pcfg, seed, d, pool)
+    dist = traffic.Distributions.from_mix(cell.mix, fam.TRAFFIC_KEYS)
+    pool = fam.make_pool(seed, POOL_LAUNCHES * d, cell.cfg, dist)
+    params, step = build(fam, pcfg, seed, d, pool)
     if fault == "control_bfloat16":
-        from reference import dlrm as ref
-
+        ref = reference(cell.cfg)
         params = ref.init(seed, cell.cfg)
         fwd = ref.forward_fn(cell.cfg, "bfloat16")
 
         def step(p, b):  # the control in the program's place
-            return fwd(p, b["dense"], b["sparse_ids"]).astype(np.float32)
+            return fwd(p, b).astype(np.float32)
 
     spans = Spans()
-    server = Server(step, params, pool, d, spans, fault)
+    server = Server(step, params, pool, d, spans, fam, fault)
     for _ in range(2):  # warm the whole path at its one shape
         server.launch(0, d)
     open_loop = cell.mix["kind"] == "open_loop"
@@ -480,7 +498,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             shutil.rmtree(trace_dir, ignore_errors=True)
         log(f"trace: {r.trace.launches} step runs in {r.trace.window_s:.6f} s, "
             f"busy {r.trace.busy_s:.6f} s, device s by layer {r.trace.layer_s}, "
-            f"dense layer bound by {readings.dense_bound(r)[0]}")
+            f"by scope {r.trace.scope_s}")
         device["busy_s"] = r.trace.busy_s
         device["window_s"] = r.trace.window_s
         result["breakdown"] = {
@@ -515,8 +533,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
 
 
 def read_trace(trace_dir: str, step, cfg: dict) -> tracing.Reduced:
-    """The traced window, reduced (see ``tracing``)."""
+    """The traced window, reduced (see ``tracing``), with device seconds by
+    the scope paths of the configuration's ``scopes`` (see ``scopes``)."""
     data = jax.profiler.ProfileData.from_file(tracing.find_xplane(trace_dir))
     ops, modules, spans = tracing.events(data)
-    layers = tracing.op_layers(step.as_text() or "", cfg["layers"])
-    return tracing.reduce(ops, modules, spans, layers, step_module="jit_serve_step")
+    text = step.as_text() or ""
+    reduced = tracing.reduce(ops, modules, spans, tracing.op_layers(text, cfg["layers"]),
+                             step_module=STEP_MODULE)
+    reduced.scope_s = scopes.scope_seconds(ops, spans, scopes.op_scopes(text, cfg["scopes"]))
+    return reduced

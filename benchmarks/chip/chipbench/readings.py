@@ -1,12 +1,10 @@
 """What the metric readers compute from a run (``harness.Run``).
 
 Each reader under ``metrics/`` calls one of these.  A reading that has
-nothing to read (no trace, no open-loop latencies, no ops of a layer)
-returns None, and the metric is left out of the result line.
+nothing to read (no trace, no open-loop latencies, no ops of a layer or a
+scope) returns None, and the metric is left out of the result line.
 """
 from __future__ import annotations
-
-import importlib
 
 import numpy as np
 
@@ -48,18 +46,31 @@ def device_ms(run) -> float | None:
     return 1e3 * t.busy_s / t.launches
 
 
+def _ms_a_launch(t, seconds: float | None) -> float | None:
+    return 1e3 * seconds / t.launches if seconds and t.launches else None
+
+
 def layer_ms(run, layer: str) -> float | None:
+    """Device ms a launch of the ops attributed to ``layer`` by source file
+    (the configuration's ``layers``)."""
     t = run.trace
-    if t is None or not t.launches or not t.layer_s.get(layer):
-        return None
-    return 1e3 * t.layer_s[layer] / t.launches
+    return None if t is None else _ms_a_launch(t, t.layer_s.get(layer))
+
+
+def scope_ms(run, path: str) -> float | None:
+    """Device ms a launch of the ops under the scope path ``path``
+    (``sparse/gather``; names from the configuration's ``scopes``)."""
+    t = run.trace
+    return None if t is None else _ms_a_launch(t, t.scope_s.get(path))
 
 
 def work(run) -> dict:
-    """Least operations and bytes of the window's launches (see the
-    configuration family's reference module)."""
-    family = importlib.import_module(f"reference.{run.cell.cfg['family']}")
-    return family.work(run.cell.cfg, run.items, run.valid_lookups, run.launches)
+    """Least operations and bytes of the window's launches (the
+    configuration family's reference, ``harness.reference``)."""
+    from chipbench import harness
+
+    return harness.reference(run.cell.cfg).work(
+        run.cell.cfg, run.items, run.valid_lookups, run.launches)
 
 
 def sparse_roofline(run) -> float | None:
@@ -91,6 +102,4 @@ def step_mfu(run) -> float | None:
     t = run.trace
     if t is None:
         return None
-    w = work(run)
-    flops = w["dense_flops"] + w["sparse_flops"]
-    return 100.0 * flops / t.window_s / run.peaks["flops_per_s"]
+    return 100.0 * work(run)["flops"] / t.window_s / run.peaks["flops_per_s"]

@@ -1,8 +1,9 @@
 """Device time by the program's own layer names.
 
-The served model runs each layer under a ``jax.named_scope``: ``sparse``
-(G_s) with ``gather`` and ``pool`` below it, ``dense`` (G_d) with ``mlp``
-and ``interaction`` below it.  The compiler keeps the scopes in every
+The served model runs each layer under a ``jax.named_scope``; the
+configuration file lists the names (``scopes``; for DLRM ``sparse`` (G_s)
+with ``gather`` and ``pool`` below it, ``dense`` (G_d) with ``mlp`` and
+``interaction`` below it).  The compiler keeps the scopes in every
 instruction's ``op_name`` metadata (``jit(serve_step)/sparse/gather/...``),
 so a trace can be read by those names rather than by source file
 (``tracing.op_layers``).  Like ``tracing``, these are pure functions of
@@ -15,8 +16,6 @@ import re
 
 from chipbench import tracing
 
-SCOPES = ("sparse", "dense", "gather", "pool", "mlp", "interaction")
-
 _HEAD = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)")
 _INSTR = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
@@ -26,12 +25,15 @@ _NAME = re.compile(r"%?([\w.\-]+)")
 
 def scope_path(op_name: str, names) -> list[str] | None:
     """The scopes of ``names`` in an ``op_name``, outermost first, with its
-    last component (the primitive) dropped.  None for a name that was not
-    traced from a jitted function (a parameter's, or one the compiler made
-    up): it says nothing about the layer."""
+    last component (the primitive) dropped; with ``names`` None, every
+    component but a transformation's (``jit(...)``, ``vmap(...)``).  None
+    for a name that was not traced from a jitted function (a parameter's,
+    or one the compiler made up): it says nothing about the layer."""
     parts = op_name.split("/")
     if not parts[0].startswith("jit("):
         return None
+    if names is None:
+        return [p for p in parts[1:-1] if "(" not in p]
     return [p for p in parts[:-1] if p in names]
 
 
@@ -45,7 +47,7 @@ def _shared(paths: list[list[str]]) -> list[str]:
     return out
 
 
-def op_scopes(hlo_text: str, names=SCOPES) -> dict[str, str]:
+def op_scopes(hlo_text: str, names=None) -> dict[str, str]:
     """Entry-computation instruction name -> scope path (``sparse/gather``;
     ``""`` for none).
 

@@ -130,6 +130,8 @@ class Reduced:
     layer_s: dict[str, float]         # device seconds by layer
     top_ops: list[tuple[str, float]]  # (layer:op, seconds), longest first
     idle_by_span: dict[str, float]    # idle seconds by host span
+    # device seconds by scope path (``scopes.scope_seconds``)
+    scope_s: dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def idle_share(self) -> float:

@@ -2,7 +2,8 @@
 
 The distributions are those of the program's click-log generator (the
 paper's Fig. 2), drawn here in bulk with numpy so that a pool of items is
-made in set-up in about a second:
+made in set-up in about a second.  Each configuration family draws its
+items' arrays from these (``make_pool`` in ``chipbench/families/``):
 
 - ids: frequency-ranked power law, ``id = floor(V ** (u ** alpha)) - 1``
   for ``u ~ U(0, 1)``, so id 0 is the hottest row of each table;
@@ -33,10 +34,23 @@ class Distributions:
     query_size_mu: float = float(np.log(64))
     query_size_sigma: float = 1.1
     query_size_max: int = 1024
+    # further parameters of the traffic file, read by a family's pool
+    # (chipbench/families/<family>.py), such as a history's length
+    extra: dict = dataclasses.field(default_factory=dict)
 
     @classmethod
-    def from_mix(cls, mix: dict) -> "Distributions":
-        return cls(**mix.get("distributions", {}))
+    def from_mix(cls, mix: dict, extra_keys=()) -> "Distributions":
+        """The traffic file's distributions; ``extra_keys`` are the further
+        parameters the family's pool reads (its ``TRAFFIC_KEYS``).  Any
+        other key is refused, so that a misspelled one does not run the
+        defaults."""
+        given = dict(mix.get("distributions", {}))
+        known = {f.name for f in dataclasses.fields(cls)} - {"extra"}
+        unknown = set(given) - known - set(extra_keys)
+        if unknown:
+            raise SystemExit(f"traffic parameters {sorted(unknown)} are read by nothing; "
+                             f"known: {sorted(known | set(extra_keys))}")
+        return cls(**{k: given.pop(k) for k in known & set(given)}, extra=given)
 
 
 def zipf_ids(rng: np.random.Generator, vocab: int, size, alpha: float):
@@ -60,18 +74,17 @@ def query_sizes(rng: np.random.Generator, n: int, dist: Distributions):
 
 @dataclasses.dataclass
 class Pool:
-    """Item features, each row one candidate item to score."""
+    """Candidate items to score: row ``i`` of every array is item ``i``."""
 
-    dense: np.ndarray   # [N, num_dense] float32
-    ids: np.ndarray     # [N, F, P] int32, -1 past each bag's count
-    counts: np.ndarray  # [N, F] valid ids per bag
+    arrays: dict[str, np.ndarray]  # the step's inputs by name, each [N, ...]
+    counts: np.ndarray             # [N, ...] valid table lookups of each item
 
     def __post_init__(self):
-        per_item = self.counts.sum(axis=1, dtype=np.int64)
+        per_item = self.counts.reshape(len(self.counts), -1).sum(axis=1, dtype=np.int64)
         self._cum = np.concatenate([[0], np.cumsum(per_item)])
 
     def __len__(self) -> int:
-        return len(self.dense)
+        return len(self.counts)
 
     def lookups(self, start: int, n: int) -> int:
         """Valid ids of ``n`` items from ``start``, round the pool."""
@@ -81,17 +94,6 @@ class Pool:
         e = s + rest
         part = cum[e] - cum[s] if e <= N else (cum[N] - cum[s]) + cum[e - N]
         return int(laps * cum[N] + part)
-
-
-def make_pool(seed: int, n: int, cfg: dict, dist: Distributions) -> Pool:
-    """``n`` items for a DLRM configuration, from ``seed``."""
-    rng = np.random.default_rng([seed, 1])
-    F, P, V = cfg["num_tables"], cfg["pooling"], cfg["rows_per_table"]
-    counts = pooling_counts(rng, P, (n, F), dist.pooling_sigma)
-    ids = zipf_ids(rng, V, (n, F, P), dist.zipf_alpha)
-    ids[np.arange(P)[None, None, :] >= counts[..., None]] = -1
-    dense = rng.standard_normal((n, cfg["num_dense"]), np.float32)
-    return Pool(dense=dense, ids=ids, counts=counts)
 
 
 def _stratified(n: int) -> np.ndarray:

@@ -13,9 +13,9 @@ check).  Besides its result, the JSON line on standard output holds, under
   the window and its seconds by host span (``assemble``, ``device_put``,
   ``dispatch``, ``wait``, ``readback``), to place a stall;
 - with ``--trace 1``: ``scope_s`` and ``scope_ms``, device seconds in the
-  window and ms a launch by the program's scope paths
-  (``chipbench.scopes``; ``""`` is no scope), and ``idle_s``, the idle
-  seconds inside step runs and between them.
+  window and ms a launch by every scope path of the configuration's
+  ``scopes`` (``harness.read_trace``; ``""`` is no scope), and ``idle_s``,
+  the idle seconds inside step runs and between them.
 
 Exits non-zero, with no result, unless JAX finds a TPU with the cell's
 chips.
@@ -32,7 +32,6 @@ import sys  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
-STEP_MODULE = "jit_serve_step"
 
 
 class LaunchSpans:
@@ -76,9 +75,8 @@ def probe(cell, seed: int, seconds: float, trace: bool, t_start: float,
         reduced = plain_read_trace(trace_dir, step, cfg)
         data = jax.profiler.ProfileData.from_file(tracing.find_xplane(trace_dir))
         ops, modules, spans = tracing.events(data)
-        paths = scopes.op_scopes(step.as_text() or "")
-        seen["scope_s"] = scopes.scope_seconds(ops, spans, paths)
-        seen["idle_s"] = scopes.idle_split(ops, modules, spans, STEP_MODULE)
+        seen["scope_s"] = reduced.scope_s
+        seen["idle_s"] = scopes.idle_split(ops, modules, spans, harness.STEP_MODULE)
         seen["launches"] = reduced.launches
         return reduced
 

@@ -116,8 +116,10 @@ def forward(params: dict, dense, ids, cfg: dict, precision: str = "default"):
 
 
 def forward_fn(cfg: dict, precision: str):
-    """The jitted forward for one precision."""
-    return jax.jit(lambda p, dense, ids: forward(p, dense, ids, cfg, precision))
+    """The jitted forward for one precision, of a launch's batch
+    ``{"dense": [B, num_dense], "sparse_ids": [B, F, P]}``."""
+    return jax.jit(lambda p, batch: forward(p, batch["dense"], batch["sparse_ids"],
+                                            cfg, precision))
 
 
 # ---------------------------------------------------------------------------
@@ -153,12 +155,16 @@ def work(cfg: dict, items: int, valid_lookups: int, launches: int) -> dict:
     per element of each valid row.
     dense: its operations; its weights read once per launch, and per item
     the pooled vectors and dense features read and one logit written.
+    flops: the whole forward's, sparse and dense.
     """
     d, F, P = cfg["embedding_dim"], cfg["num_tables"], cfg["pooling"]
+    sparse_flops = valid_lookups * d
+    dense_flops = items * dense_flops_per_item(cfg)
     return {
+        "flops": sparse_flops + dense_flops,
         "sparse_bytes": 4 * (valid_lookups * d + items * F * (P + d)),
-        "sparse_flops": valid_lookups * d,
-        "dense_flops": items * dense_flops_per_item(cfg),
+        "sparse_flops": sparse_flops,
+        "dense_flops": dense_flops,
         "dense_bytes": (launches * dense_weight_bytes(cfg)
                         + 4 * items * (F * d + cfg["num_dense"] + 1)),
     }

@@ -15,6 +15,7 @@ for p in (str(BENCH), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 from chipbench import harness  # noqa: E402
+from chipbench.families import dlrm as dlrm_family  # noqa: E402
 
 D = 64
 
@@ -54,6 +55,6 @@ def tiny_program(monkeypatch):
                 row_pad=cfg["weights"]["row_pad"]))
 
     monkeypatch.setattr(harness, "compile_cache", lambda: None)
-    monkeypatch.setattr(harness, "program_config", program_config)
+    monkeypatch.setattr(dlrm_family, "program_config", program_config)
     monkeypatch.setattr(harness, "schedule",
                         lambda cfg, pcfg: ({"plan": "tiny", "d": D, "m": 1, "o": 1}, 0.0))

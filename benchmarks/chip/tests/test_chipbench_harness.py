@@ -2,6 +2,7 @@
 run on the CPU at a tiny size, sound and with the timed path broken."""
 from __future__ import annotations
 
+import copy
 import json
 import os
 import re
@@ -11,7 +12,7 @@ import time
 
 import pytest
 
-from chipbench_tiny import BENCH, ROOT, harness, tiny_cell, tiny_program
+from chipbench_tiny import BENCH, ROOT, dlrm_family, harness, tiny_cell, tiny_program
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -92,3 +93,18 @@ def test_sound_run_is_correct(monkeypatch, kind):
 def test_broken_timed_path_is_not_correct(monkeypatch, kind, fault):
     res = _run(monkeypatch, kind, fault)
     assert res["correct"] is False, (fault, res["compared"])
+
+
+@pytest.mark.parametrize("row_pad, rows", [(512, 10_000_384), (2048, 10_000_384),
+                                           (1000, 10_000_000), (4096, 10_002_432)])
+def test_program_config_checks_the_padded_rows(row_pad, rows):
+    """RMC1's program pads its packed table to 10,000,384 rows (lines of
+    four, to 512 lines); a file whose reference pads to another count
+    would draw other weights, and is refused with both counts."""
+    cfg = copy.deepcopy(harness.load_json(BENCH / "configs" / "dlrm-rmc1.json"))
+    cfg["weights"]["row_pad"] = row_pad
+    if rows == 10_000_384:
+        assert dlrm_family.program_config(cfg).embedding.total_rows == rows
+    else:
+        with pytest.raises(SystemExit, match=f"10000384 rows.* to {rows}"):
+            dlrm_family.program_config(cfg)

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench_tiny import BENCH, harness, tiny_program
+from chipbench_tiny import BENCH, dlrm_family, harness, tiny_program
 from chipbench import traffic
 from reference import dlrm as ref
 
@@ -28,7 +28,7 @@ def program(cfg, monkeypatch, seed, pool):
     """The program's weights and compiled step, built as the harness builds
     them."""
     tiny_program(monkeypatch)
-    return harness.build(harness.program_config(cfg), seed, B, pool)
+    return harness.build(dlrm_family, dlrm_family.program_config(cfg), seed, B, pool)
 
 
 def gap(got, want):
@@ -40,7 +40,7 @@ def gap(got, want):
 def test_reference_weights_are_the_programs(name, monkeypatch):
     cfg = small(name)
     seed = 2**31 + 17
-    pool = traffic.make_pool(seed, B, cfg, traffic.Distributions())
+    pool = dlrm_family.make_pool(seed, B, cfg, traffic.Distributions())
     params, _ = program(cfg, monkeypatch, seed, pool)
     mine = ref.init(seed, cfg)
     np.testing.assert_array_equal(mine["table"], params["embedding"]["table"])
@@ -53,16 +53,16 @@ def test_reference_weights_are_the_programs(name, monkeypatch):
 def test_reference_agrees_and_bfloat16_fails(name, monkeypatch):
     cfg = small(name)
     seed = 12345
-    pool = traffic.make_pool(seed, B, cfg, traffic.Distributions())
+    pool = dlrm_family.make_pool(seed, B, cfg, traffic.Distributions())
     params, step = program(cfg, monkeypatch, seed, pool)
-    got = step(params, {"dense": pool.dense, "sparse_ids": pool.ids})
+    batch = {k: jnp.asarray(a) for k, a in pool.arrays.items()}
+    got = step(params, batch)
     rp = ref.init(seed, cfg)
-    args = (rp, jnp.asarray(pool.dense), jnp.asarray(pool.ids))
-    want = ref.forward_fn(cfg, "default")(*args)
+    want = ref.forward_fn(cfg, "default")(rp, batch)
     limit = cfg["check"]["score_gap_limit"]
     assert gap(got, want) < limit / 100
-    low = ref.forward_fn(cfg, "bfloat16")(*args)
+    low = ref.forward_fn(cfg, "bfloat16")(rp, batch)
     assert gap(low, want) > 2 * limit
     # dropping the sparse half (every bag empty) is caught too
-    empty = ref.forward_fn(cfg, "default")(rp, args[1], jnp.full_like(args[2], -1))
-    assert gap(empty, want) > 2 * limit
+    empty = dict(batch, sparse_ids=jnp.full_like(batch["sparse_ids"], -1))
+    assert gap(ref.forward_fn(cfg, "default")(rp, empty), want) > 2 * limit

@@ -1,18 +1,21 @@
 """Device time by the program's scope names: the scope map of a compiled
-step, its reduction over a synthetic trace, the idle split, the tiny DLRM
-step's scopes, and the probe's readings on a tiny CPU run and its refusal
-without a chip."""
+step, its reduction over a synthetic trace and the readers of scopes named
+by the configuration, the idle split, the tiny DLRM step's scopes, and the
+probe's readings on a tiny CPU run and its refusal without a chip."""
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
 import time
+import types
 
 import pytest
 
-from chipbench_tiny import BENCH, D, ROOT, harness, tiny_cell, tiny_program
-from chipbench import scopes, tracing, traffic
+from chipbench_tiny import BENCH, D, ROOT, dlrm_family, harness, tiny_cell, tiny_program
+from chipbench import readings, scopes, tracing, traffic
+
+RMC1_SCOPES = harness.load_json(BENCH / "configs" / "dlrm-rmc1.json")["scopes"]
 
 # A compiled step's HLO text as the TPU compiler prints it (metadata cut to
 # ``op_name``): fused computations first, then the entry computation.
@@ -94,12 +97,14 @@ ENTRY %main.22 (p: f32[10,32]) -> f32[4] {
     ("copy.9", ""),                     # traced, under no scope
     ("p", ""),                          # a parameter's name
 ])
-def test_op_scopes(op, path):
-    assert scopes.op_scopes(HLO)[op] == path
+@pytest.mark.parametrize("names", [RMC1_SCOPES, None])
+def test_op_scopes(op, path, names):
+    # None keeps every component but a transformation's (``jit(_take)``)
+    assert scopes.op_scopes(HLO, names)[op] == path
 
 
 def test_op_scopes_maps_the_entry_computation_only():
-    got = scopes.op_scopes(HLO)
+    got = scopes.op_scopes(HLO, RMC1_SCOPES)
     assert {"mul.1", "custom-call.3", "sum"}.isdisjoint(got)
     assert got["copy-start"] == got["tuple.3"] == ""
     # names outside the list are not scopes: without ``gather`` the gather
@@ -123,11 +128,32 @@ def _trace():
 
 def test_scope_seconds_are_counted_as_layer_seconds():
     ops, modules, spans = _trace()
-    got = scopes.scope_seconds(ops, spans, scopes.op_scopes(HLO))
+    got = scopes.scope_seconds(ops, spans, scopes.op_scopes(HLO, RMC1_SCOPES))
     assert got == pytest.approx({"sparse/gather": 0.003, "sparse": 0.0005,
                                  "dense/interaction": 0.001, "": 0.0002})
     red = tracing.reduce(ops, modules, spans, {}, step_module="jit_serve_step")
     assert sum(got.values()) == pytest.approx(sum(red.layer_s.values()))
+
+
+@pytest.mark.parametrize("names, want", [
+    (RMC1_SCOPES, {"gather_ms.bulk": 1.5, "pool_ms.bulk": None,
+                   "sparse": 0.25, "dense/interaction": 0.5}),
+    # a configuration naming only the two halves: the gather is ``sparse``
+    (["sparse", "dense"], {"gather_ms.bulk": None, "pool_ms.bulk": None,
+                           "sparse": 1.75, "dense/interaction": None}),
+])
+def test_scope_readers_read_the_configurations_scopes(names, want):
+    ops, modules, spans = _trace()
+    red = tracing.reduce(ops, modules, spans, {}, step_module=harness.STEP_MODULE)
+    red.scope_s = scopes.scope_seconds(ops, spans, scopes.op_scopes(HLO, names))
+    run = types.SimpleNamespace(trace=red)
+    assert red.launches == 2
+    got = {m: harness.reader(m)(run) for m in ("gather_ms.bulk", "pool_ms.bulk")}
+    got.update({p: readings.scope_ms(run, p) for p in ("sparse", "dense/interaction")})
+    assert got == {k: (v if v is None else pytest.approx(v)) for k, v in want.items()}
+    # the scopes' seconds sum to the ops' seconds, as the layers' do
+    assert sum(red.scope_s.values()) == pytest.approx(sum(red.layer_s.values()))
+    assert readings.scope_ms(types.SimpleNamespace(trace=None), "sparse") is None
 
 
 def test_idle_splits_into_inside_and_between_step_runs():
@@ -146,16 +172,16 @@ LEAVES = {"sparse/gather", "sparse/pool", "dense/mlp", "dense/interaction"}
 def test_tiny_dlrm_step_on_the_cpu(monkeypatch):
     tiny_program(monkeypatch)
     cell = tiny_cell()
-    pcfg = harness.program_config(cell.cfg)
+    pcfg = dlrm_family.program_config(cell.cfg)
     dist = traffic.Distributions.from_mix(cell.mix)
-    pool = traffic.make_pool(2**31 + 11, D, cell.cfg, dist)
-    _, step = harness.build(pcfg, 2**31 + 11, D, pool)
+    pool = dlrm_family.make_pool(2**31 + 11, D, cell.cfg, dist)
+    _, step = harness.build(dlrm_family, pcfg, 2**31 + 11, D, pool)
     text = step.as_text()
     assert all(f"/{leaf}/" in text for leaf in LEAVES)
     # The CPU compiler fuses the whole of G_s into G_d's first op, so only
     # G_d's leaves remain (the v5e compile of RMC1 keeps all four:
     # tests/test_tpu_compile.py); what is left maps to scope paths alone.
-    paths = set(scopes.op_scopes(text).values())
+    paths = set(scopes.op_scopes(text, cell.cfg["scopes"]).values())
     assert {"dense/mlp", "dense/interaction"} <= paths <= LEAVES | {"", "sparse", "dense"}
 
 
