@@ -88,10 +88,25 @@ def din(prod: bool = True) -> RecsysConfig:
     )
 
 
+# DIEN's item categories: neither Table I nor the DIEN paper gives a count.
+DIEN_CATEGORIES = 10_000
+
+
 def dien(prod: bool = True) -> RecsysConfig:
+    # DIN's tables plus a category table; a behaviour is item ⊕ category
+    # (36 wide) and both GRUs are 36 wide, as in the DIEN authors' released
+    # code (EMBEDDING_DIM 18, HIDDEN_SIZE 36)
     import dataclasses
 
-    return dataclasses.replace(din(prod), name="dien", use_gru=True)
+    base = din(prod)
+    item, *profile = base.embedding.vocab_sizes
+    emb = dataclasses.replace(
+        base.embedding,
+        vocab_sizes=(item, DIEN_CATEGORIES, *profile),
+        pooling=(1,) * (2 + len(profile)),
+    )
+    return dataclasses.replace(base, name="dien", embedding=emb, use_gru=True,
+                               item_category=True)
 
 
 PAPER_MODELS = {

@@ -135,26 +135,28 @@ def profile_recsys(cfg: RecsysConfig, sla_ms: float) -> ModelProfile:
             ops.append(_mlp_cost(f"tower_{t}", "dense", 2, (cfg.top_mlp[-1], 1), db))
     elif cfg.interaction == "target-attn":
         T = cfg.seq_len
+        n_beh = cfg.behaviour_tables  # rows a behaviour concatenates
+        w = n_beh * d
         # history embedding gather (the model's SparseNet)
         ops.append(OpCost(
             name="emb_hist", stage="sparse", level=0,
-            flops=T * d, gather_bytes=(T + 1) * d * db, host_bytes=(T + 1) * 8.0,
-            stream_bytes=T * d * db,
+            flops=T * w, gather_bytes=(T + 1) * w * db,
+            host_bytes=(T + 1) * n_beh * 8.0, stream_bytes=T * w * db,
         ))
-        attn_sizes = (4 * d, *cfg.attn_mlp, 1)
+        attn_sizes = (4 * w, *cfg.attn_mlp, 1)
         attn = _mlp_cost("attn_unit", "dense", 1, attn_sizes, db)
         ops.append(dataclasses.replace(
             attn, flops=attn.flops * T, stream_bytes=attn.stream_bytes * T))
         if cfg.use_gru:  # DIEN: two GRU passes, sequential over T
-            gru_flops = 2 * T * 6.0 * d * d * 2.0
+            gru_flops = 2 * T * 6.0 * w * w * 2.0
             ops.append(OpCost(
                 name="gru", stage="dense", level=1, flops=gru_flops,
-                stream_bytes=2 * T * d * db, weight_bytes=12 * d * d * db,
+                stream_bytes=2 * T * w * db, weight_bytes=12 * w * w * db,
                 sequential=True,
             ))
-        n_profile = cfg.embedding.num_features - 1
+        n_profile = cfg.embedding.num_features - n_beh
         ops.append(_mlp_cost(
-            "top_mlp", "dense", 2, ((2 + n_profile) * d, *cfg.top_mlp, 1), db))
+            "top_mlp", "dense", 2, (2 * w + n_profile * d, *cfg.top_mlp, 1), db))
     elif cfg.interaction == "multi-interest":
         T, K = cfg.seq_len, cfg.n_interests
         ops.append(OpCost(

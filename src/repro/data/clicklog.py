@@ -106,11 +106,16 @@ class ClickLogGenerator:
             mask = np.arange(cfg.seq_len)[None, :] < lengths[:, None]
             b["history_ids"] = np.where(mask, hist, -1).astype(np.int32)
             b["target_id"] = self._zipf_ids(item_vocab, batch_size).astype(np.int32)
-            if emb.num_features > 1:
+            first = cfg.behaviour_tables
+            if cfg.item_category:  # an item's category: its id modulo the count
+                n_cat = emb.vocab_sizes[1]
+                b["history_cat_ids"] = np.where(mask, hist % n_cat, -1).astype(np.int32)
+                b["target_cat_id"] = (b["target_id"] % n_cat).astype(np.int32)
+            if emb.num_features > first:
                 b["profile_ids"] = np.stack(
                     [
                         self._zipf_ids(emb.vocab_sizes[f], batch_size)
-                        for f in range(1, emb.num_features)
+                        for f in range(first, emb.num_features)
                     ],
                     axis=1,
                 ).astype(np.int32)

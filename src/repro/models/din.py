@@ -1,70 +1,89 @@
 """DIN (arXiv:1706.06978) and DIEN (arXiv:1809.03672).
 
 Embedding layout convention for behaviour-sequence models: feature 0 of the
-EmbeddingConfig is the ITEM table (shared by history_ids and target_id);
-features 1..F-1 are 1-hot profile/context tables looked up via
-batch["profile_ids"] [B, F-1].
+EmbeddingConfig is the ITEM table (shared by history_ids and target_id).
+With ``cfg.item_category`` feature 1 is the CATEGORY table (shared by
+history_cat_ids and target_cat_id), and a behaviour or the target is its
+item's row followed by its category's row, as the DIEN authors' released
+code builds them.  The remaining features are 1-hot profile/context tables
+looked up via batch["profile_ids"] [B, n_profile].
 
 DIN: local activation unit — per history item, an MLP over
 [e_h, e_t, e_h - e_t, e_h * e_t] produces an attention weight; the weighted
 sum of history embeddings is the user interest vector.
 
 DIEN (cfg.use_gru): interest-extractor GRU over history, then AUGRU
-(attention-update-gate GRU) with DIN-style scores drives interest evolution.
+(attention-update-gate GRU) with DIN-style scores drives interest evolution;
+both recurrences live in ``models/gru.py``.
+
+``apply`` names its layers with ``jax.named_scope``: ``sparse`` (the history,
+target and profile lookups), then ``dense``, holding ``gru`` (the interest
+extractor), ``attention`` (the attention unit and its softmax or weighted
+sum), ``augru`` (interest evolution) and the top MLP's ``mlp``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.common.init import normal_init
 from repro.models import embedding as emb_lib
+from repro.models import gru as gru_lib
 from repro.models.layers import apply_mlp, init_mlp
 from repro.models.recsys_base import RecsysConfig
 
 
-def _item_lookup(params, ids, cfg: RecsysConfig):
-    """Lookup into the item table (feature 0). ids >= 0; -1 padded -> 0 row.
+def behaviour_dim(cfg: RecsysConfig) -> int:
+    """Width of a behaviour and of the target: the item's row, followed by
+    its category's row where ``cfg.item_category``.  Both GRUs' hidden size."""
+    return cfg.embed_dim * cfg.behaviour_tables
+
+
+def _rows(params, ids, feature: int, cfg: RecsysConfig):
+    """Rows of ``feature``'s table; a -1 id reads the table's row 0.
 
     Routes through the model-axis-sharded gather under a mesh context."""
     from repro.dist.sharded_embedding import sharded_row_gather
 
-    base = int(cfg.embedding.row_offsets[0])
-    safe = jnp.maximum(ids, 0)
-    return sharded_row_gather(params["embedding"]["table"], base + safe, None)
+    base = int(cfg.embedding.row_offsets[feature])
+    return sharded_row_gather(params["embedding"]["table"], base + jnp.maximum(ids, 0), None)
+
+
+def _behaviour_lookup(params, item_ids, cat_ids, cfg: RecsysConfig):
+    """Behaviour rows [..., behaviour_dim]: the item's row, then (with
+    ``cfg.item_category``) the row of ``cat_ids`` in the category table."""
+    rows = _rows(params, item_ids, 0, cfg)
+    if cfg.item_category:
+        rows = jnp.concatenate([rows, _rows(params, cat_ids, 1, cfg)], axis=-1)
+    return rows
 
 
 def _profile_lookup(params, profile_ids, cfg: RecsysConfig):
-    """1-hot lookups for features 1..F-1 -> [B, (F-1)*D]."""
-    from repro.dist.sharded_embedding import sharded_row_gather
-
-    offs = cfg.embedding.row_offsets
+    """1-hot lookups of the profile features (those after the behaviour's)
+    -> [B, n_profile * D].  A missing id (-1) gives the zero vector, as a
+    masked history step does."""
+    valid = (profile_ids >= 0).astype(cfg.dtype)
+    first = cfg.behaviour_tables
     outs = []
-    for f in range(1, cfg.embedding.num_features):
-        outs.append(
-            sharded_row_gather(
-                params["embedding"]["table"],
-                int(offs[f]) + profile_ids[:, f - 1],
-                None,
-            )
-        )
+    for f in range(first, cfg.embedding.num_features):
+        rows = _rows(params, profile_ids[:, f - first], f, cfg)
+        outs.append(rows * valid[:, f - first, None])
     return jnp.concatenate(outs, axis=-1)
 
 
 def init(key, cfg: RecsysConfig):
     k_emb, k_attn, k_top, k_gru1, k_gru2 = jax.random.split(key, 5)
-    d = cfg.embed_dim
+    w = behaviour_dim(cfg)
     params = {
         "embedding": emb_lib.init_embedding(k_emb, cfg.embedding),
         # attention unit input: [e_h, e_t, e_h - e_t, e_h * e_t]
-        "attn_mlp": init_mlp(k_attn, (4 * d, *cfg.attn_mlp, 1), dtype=cfg.dtype),
+        "attn_mlp": init_mlp(k_attn, (4 * w, *cfg.attn_mlp, 1), dtype=cfg.dtype),
     }
-    n_profile = cfg.embedding.num_features - 1
-    top_in = 2 * d + n_profile * d  # [interest, e_target, profiles]
+    n_profile = cfg.embedding.num_features - cfg.behaviour_tables
+    top_in = 2 * w + n_profile * cfg.embed_dim  # [interest, e_target, profiles]
     params["top_mlp"] = init_mlp(k_top, (top_in, *cfg.top_mlp, 1), dtype=cfg.dtype)
     if cfg.use_gru:
-        params["gru"] = _init_gru(k_gru1, d, d, dtype=cfg.dtype)
-        params["augru"] = _init_gru(k_gru2, d, d, dtype=cfg.dtype)
+        params["gru"] = gru_lib.init_gru(k_gru1, w, w, dtype=cfg.dtype)
+        params["augru"] = gru_lib.init_gru(k_gru2, w, w, dtype=cfg.dtype)
     return params
 
 
@@ -78,70 +97,38 @@ def attention_scores(params, hist_emb, target_emb, mask, cfg: RecsysConfig):
     return jnp.where(mask, logit, 0.0)
 
 
-def _init_gru(key, in_dim, hidden, dtype=jnp.float32):
-    ks = jax.random.split(key, 3)
-    def gate(k):
-        return {
-            "wx": normal_init(k, (in_dim, hidden), stddev=0.05, dtype=dtype),
-            "wh": normal_init(jax.random.fold_in(k, 1), (hidden, hidden), stddev=0.05, dtype=dtype),
-            "b": jnp.zeros((hidden,), dtype),
-        }
-    return {"r": gate(ks[0]), "z": gate(ks[1]), "h": gate(ks[2])}
-
-
-def _gru_cell(p, h, x, update_scale=None):
-    r = jax.nn.sigmoid(x @ p["r"]["wx"] + h @ p["r"]["wh"] + p["r"]["b"])
-    z = jax.nn.sigmoid(x @ p["z"]["wx"] + h @ p["z"]["wh"] + p["z"]["b"])
-    hh = jnp.tanh(x @ p["h"]["wx"] + (r * h) @ p["h"]["wh"] + p["h"]["b"])
-    if update_scale is not None:  # AUGRU: attention scales the update gate
-        z = z * update_scale[:, None]
-    return (1.0 - z) * h + z * hh
-
-
-def _run_gru(p, xs, att=None):
-    """xs [B, T, D] -> all hidden states [B, T, D] via lax.scan over T."""
-    B, T, D = xs.shape
-    h0 = jnp.zeros((B, D), xs.dtype)
-    xs_t = xs.swapaxes(0, 1)  # [T, B, D]
-    if att is None:
-        def step(h, x):
-            h = _gru_cell(p, h, x)
-            return h, h
-        _, hs = jax.lax.scan(step, h0, xs_t)
-    else:
-        def step_a(h, inp):
-            x, a = inp
-            h = _gru_cell(p, h, x, update_scale=a)
-            return h, h
-        _, hs = jax.lax.scan(step_a, h0, (xs_t, att.swapaxes(0, 1)))
-    return hs.swapaxes(0, 1)  # [B, T, D]
-
-
 def apply(params, batch, cfg: RecsysConfig) -> jax.Array:
     hist = batch["history_ids"]                     # [B, T]
     mask = hist >= 0
-    hist_emb = _item_lookup(params, hist, cfg) * mask[..., None].astype(cfg.dtype)
-    target_emb = _item_lookup(params, batch["target_id"], cfg)  # [B, D]
+    cats = (batch["history_cat_ids"], batch["target_cat_id"]) if cfg.item_category else (None, None)
+    with jax.named_scope("sparse"):
+        hist_emb = _behaviour_lookup(params, hist, cats[0], cfg)
+        hist_emb = hist_emb * mask[..., None].astype(cfg.dtype)  # [B, T, W]
+        target_emb = _behaviour_lookup(params, batch["target_id"], cats[1], cfg)  # [B, W]
+        feats = [target_emb]
+        if cfg.embedding.num_features > cfg.behaviour_tables and "profile_ids" in batch:
+            feats.append(_profile_lookup(params, batch["profile_ids"], cfg))
 
-    if cfg.use_gru:  # DIEN
-        states = _run_gru(params["gru"], hist_emb)              # interest extractor
-        att = attention_scores(params, states, target_emb, mask, cfg)
-        att = jax.nn.softmax(jnp.where(mask, att, -1e30), axis=-1)
-        final = _run_gru(params["augru"], states, att=att)[:, -1, :]
-        interest = final
-    else:  # DIN
-        att = attention_scores(params, hist_emb, target_emb, mask, cfg)
-        interest = jnp.einsum("bt,btd->bd", att, hist_emb)
+    with jax.named_scope("dense"):
+        if cfg.use_gru:  # DIEN
+            with jax.named_scope("gru"):                # interest extractor
+                states = gru_lib.run_gru(params["gru"], hist_emb)
+            with jax.named_scope("attention"):
+                att = attention_scores(params, states, target_emb, mask, cfg)
+                att = jax.nn.softmax(jnp.where(mask, att, -1e30), axis=-1)
+            with jax.named_scope("augru"):              # interest evolution
+                interest = gru_lib.run_gru(params["augru"], states, att=att)[:, -1, :]
+        else:  # DIN
+            with jax.named_scope("attention"):
+                att = attention_scores(params, hist_emb, target_emb, mask, cfg)
+                interest = jnp.einsum("bt,btd->bd", att, hist_emb)
 
-    feats = [interest, target_emb]
-    if cfg.embedding.num_features > 1 and "profile_ids" in batch:
-        feats.append(_profile_lookup(params, batch["profile_ids"], cfg))
-    x = jnp.concatenate(feats, axis=-1)
-    return apply_mlp(params["top_mlp"], x)[:, 0]
+        x = jnp.concatenate([interest, *feats], axis=-1)
+        return apply_mlp(params["top_mlp"], x)[:, 0]
 
 
 def retrieval_scores(params, batch, candidate_ids, cfg: RecsysConfig) -> jax.Array:
-    """Score one user's history against N candidate items -> [N].
+    """Score one user's history against N candidate items -> [N] (DIN: item rows).
 
     DIN's attention depends on the target, so each candidate re-attends over
     the history — but the history embeddings are gathered ONCE (not N times)
@@ -149,16 +136,16 @@ def retrieval_scores(params, batch, candidate_ids, cfg: RecsysConfig) -> jax.Arr
     """
     hist = batch["history_ids"][0]                       # [T]
     mask = hist >= 0
-    hist_emb = _item_lookup(params, hist, cfg)           # [T, D]
+    hist_emb = _rows(params, hist, 0, cfg)               # [T, D]
     hist_emb = hist_emb * mask[:, None].astype(cfg.dtype)
-    cand_emb = _item_lookup(params, candidate_ids, cfg)  # [N, D]
+    cand_emb = _rows(params, candidate_ids, 0, cfg)      # [N, D]
     N, D = cand_emb.shape
     T = hist.shape[0]
     h = jnp.broadcast_to(hist_emb[None], (N, T, D))
     att = attention_scores(params, h, cand_emb, jnp.broadcast_to(mask[None], (N, T)), cfg)
     interest = jnp.einsum("nt,ntd->nd", att, h)
     feats = [interest, cand_emb]
-    if cfg.embedding.num_features > 1 and "profile_ids" in batch:
+    if cfg.embedding.num_features > cfg.behaviour_tables and "profile_ids" in batch:
         prof = _profile_lookup(params, batch["profile_ids"], cfg)  # [1, (F-1)D]
         feats.append(jnp.broadcast_to(prof, (N, prof.shape[-1])))
     x = jnp.concatenate(feats, axis=-1)

@@ -11,6 +11,9 @@ Batch layout (dense dict of arrays; unused keys absent):
     sparse_ids  [B, F, P]   i32     multi-hot ids, -1-padded
     history_ids [B, T]      i32     behaviour sequence (DIN/DIEN/MIND)
     target_id   [B]         i32     candidate item (DIN/DIEN/MIND)
+    history_cat_ids [B, T]  i32     the behaviours' categories (item_category)
+    target_cat_id   [B]     i32     the candidate's category (item_category)
+    profile_ids [B, n]      i32     user/context ids (DIN/DIEN)
     label       [B]         f32     click label (training)
 """
 from __future__ import annotations
@@ -38,6 +41,7 @@ class RecsysConfig:
     seq_len: int = 0
     attn_mlp: tuple[int, ...] = ()         # DIN attention-unit hidden sizes
     use_gru: bool = False                  # DIEN
+    item_category: bool = False            # behaviour = item ⊕ category (feature 1)
     n_interests: int = 0                   # MIND
     capsule_iters: int = 3                 # MIND routing iterations
     # MT-WnD:
@@ -47,6 +51,11 @@ class RecsysConfig:
     @property
     def embed_dim(self) -> int:
         return self.embedding.dim
+
+    @property
+    def behaviour_tables(self) -> int:
+        """Tables whose rows make a behaviour: the item's, and the category's."""
+        return 2 if self.item_category else 1
 
 
 def binary_ce(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -80,12 +89,15 @@ def input_specs(
         )
     if cfg.seq_len:
         specs["history_ids"] = jax.ShapeDtypeStruct((batch_size, cfg.seq_len), jnp.int32)
+        if cfg.item_category:
+            specs["history_cat_ids"] = specs["history_ids"]
         if not n_candidates:
             specs["target_id"] = jax.ShapeDtypeStruct((batch_size,), jnp.int32)
-        if emb.num_features > 1:
-            specs["profile_ids"] = jax.ShapeDtypeStruct(
-                (batch_size, emb.num_features - 1), jnp.int32
-            )
+            if cfg.item_category:
+                specs["target_cat_id"] = specs["target_id"]
+        n_profile = emb.num_features - cfg.behaviour_tables
+        if n_profile:
+            specs["profile_ids"] = jax.ShapeDtypeStruct((batch_size, n_profile), jnp.int32)
     if n_candidates:
         specs["candidate_ids"] = jax.ShapeDtypeStruct((n_candidates,), jnp.int32)
     if with_labels:

@@ -1,5 +1,5 @@
-"""Compile the main path's kernels and the RMC1 serve step for one TPU v5e
-chip that is described, not attached.
+"""Compile the main path's kernels and the RMC1 and DIEN serve steps for one
+TPU v5e chip that is described, not attached.
 
 Nothing runs: the TPU compiler refuses here what the chip would refuse
 (unaligned slices, too much fast memory, programs that do not fit).  The
@@ -7,6 +7,7 @@ topology is described inside a fixture, never at import, so every xdist
 worker collects the same tests and only the one given this file loads the
 TPU library.
 """
+import json
 import math
 import os
 import pathlib
@@ -18,11 +19,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.paper_models import rmc1
+from repro.configs.paper_models import dien, rmc1
 from repro.kernels.embedding_bag.embedding_bag import hot_embedding_bag_pallas
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 from repro.kernels.flash_attention.flash_decode import flash_decode_pallas
-from repro.models import dlrm
+from repro.models import din, dlrm
 from repro.models.recsys_base import input_specs
 
 
@@ -85,6 +86,20 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     _assert_kernel(compiled)
 
 
+BENCH = pathlib.Path(__file__).parents[1] / "benchmarks" / "chip"
+
+
+def _scope_paths(hlo: str, config: str) -> set[str]:
+    """The scope paths of the entry's ops, by the names the benchmark's
+    configuration file lists (``benchmarks/chip/chipbench/scopes.py``)."""
+    if str(BENCH) not in sys.path:
+        sys.path.insert(0, str(BENCH))
+    from chipbench.scopes import op_scopes
+
+    names = json.loads((BENCH / "configs" / config).read_text())["scopes"]
+    return set(op_scopes(hlo, names).values())
+
+
 def _entry(hlo: str) -> list[str]:
     """The entry computation's instruction lines."""
     body = hlo[hlo.index("\nENTRY "):]
@@ -104,12 +119,9 @@ def test_rmc1_serve_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > cfg.embedding.bytes()
     # each layer keeps its named scope through the compiler's fusions, so a
-    # device trace can be read by them (benchmarks/chip/chipbench/scopes.py)
-    sys.path.insert(0, str(pathlib.Path(__file__).parents[1] / "benchmarks" / "chip"))
-    from chipbench.scopes import op_scopes
-
+    # device trace can be read by them
     hlo = compiled.as_text()
-    paths = set(op_scopes(hlo).values())
+    paths = _scope_paths(hlo, "dlrm-rmc1.json")
     assert {"sparse/gather", "sparse/pool", "dense/mlp", "dense/interaction"} <= paths
 
     # the table stays rows-major, and each lookup fetches one whole line
@@ -128,3 +140,21 @@ def test_rmc1_serve_step_compiles_for_v5e(one_chip):
     assert moves  # the pattern reads this compiler's text
     for m in moves:
         assert math.prod(int(d) for d in m.group(1).split(",") if d) < block, m.string[:200]
+
+
+def test_dien_serve_step_compiles_for_v5e(one_chip):
+    cfg = dien(prod=False)
+    # 18-wide rows do not divide a 128-lane line: the table stays [rows, 18]
+    assert cfg.embedding.rows_per_line == 1
+    params = jax.eval_shape(lambda: din.init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), params)
+    batch = {k: _spec(s.shape, s.dtype, one_chip)
+             for k, s in input_specs(cfg, 1024).items()}
+    assert set(batch) == {"history_ids", "history_cat_ids", "target_id", "target_cat_id",
+                          "profile_ids"}
+    compiled = jax.jit(lambda p, b: din.apply(p, b, cfg)).lower(
+        params, batch).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > cfg.embedding.bytes()
+    # the gathers, both recurrences and the attention unit keep their names
+    paths = _scope_paths(compiled.as_text(), "dien.json")
+    assert {"sparse", "dense/gru", "dense/attention", "dense/augru"} <= paths
