@@ -12,11 +12,14 @@ Layout: all feature tables are concatenated row-wise into ONE combined
 ``f``'s ids are shifted by ``row_offsets[f]``. This gives a single gather for
 the whole SparseNet and a single row-sharded array for the model axis.
 
-Storage: 32-bit rows of 8 to 64 lanes are stored ``rows_per_line`` to a
-128-lane line (a ``LineTable``). The TPU lays a ``[R, 32]`` f32 array out
-rows-minor, so a row's floats are not contiguous and its gather crawls; a
-``[R // 4, 128]`` array keeps rows major, and one index fetches 512
-contiguous bytes. Every reader fetches rows through ``gather_rows``.
+Storage: 32-bit rows of 8 to 127 lanes are stored in 128-lane lines (a
+``LineTable``): each row padded with zero lanes to ``row_width``, the power
+of two at or above ``dim`` (18 -> 32), and ``rows_per_line = 128 //
+row_width`` rows side by side in a line. The TPU lays a ``[R, 32]`` or
+``[R, 18]`` f32 array out rows-minor, so a row's floats are not contiguous
+and its gather crawls; a ``[R // 4, 128]`` array keeps rows major, and one
+index fetches 512 contiguous bytes. Every reader fetches rows through
+``gather_rows``.
 
 Hot/cold split (paper §IV-B, locality-aware partition): ids are assumed
 frequency-ranked per table (the synthetic data generator produces them that
@@ -58,10 +61,9 @@ class EmbeddingConfig:
     qr_features: tuple[int, ...] = ()
     qr_buckets: int = 65536
     dtype: Any = jnp.float32
-    # the stored table's leading dimension (rows, or lines of
-    # ``rows_per_line`` rows) is padded to a multiple of this so the
-    # row-wise model-axis shard is always even (512 covers every
-    # production mesh).
+    # the table's rows, and a packed table's lines, are padded to a
+    # multiple of this so the row-wise model-axis shard is always even
+    # (512 covers every production mesh).
     row_pad: int = 512
 
     def __post_init__(self):
@@ -89,25 +91,40 @@ class EmbeddingConfig:
         return np.concatenate([[0], np.cumsum(sizes)]).astype(np.int64)
 
     @property
-    def rows_per_line(self) -> int:
-        """Rows stored side by side in one 128-lane line.
+    def packed(self) -> bool:
+        """Whether the table is stored in 128-lane lines (a ``LineTable``):
+        32-bit rows of 8 to 127 lanes. Other tables stay ``[rows, dim]``:
+        wider rows fill a line already, 16-bit rows are laid out otherwise,
+        and a line of narrower ones (a wide part's dim 1) would read over 16
+        rows for the one looked up."""
+        return jnp.dtype(self.dtype).itemsize == 4 and 8 <= self.dim < LANES
 
-        ``128 // dim`` for 32-bit rows of 8 to 64 lanes that divide 128
-        (4 for dim 32, 2 for dim 64); 1 otherwise, where the table stays
-        ``[rows, dim]``: wider rows fill a line already, 16-bit rows are
-        laid out otherwise, and a line of narrower ones (a wide part's dim
-        1) would read over 16 rows for the one looked up.
-        """
-        if (jnp.dtype(self.dtype).itemsize == 4 and 8 <= self.dim < LANES
-                and LANES % self.dim == 0):
-            return LANES // self.dim
-        return 1
+    @property
+    def row_width(self) -> int:
+        """Lanes a stored row takes: ``dim`` rounded up to a power of two
+        where ``packed`` (32 for dim 18, the lanes past ``dim`` zeros),
+        ``dim`` otherwise."""
+        return _row_width(self.dim) if self.packed else self.dim
+
+    @property
+    def rows_per_line(self) -> int:
+        """Rows stored side by side in one 128-lane line: ``128 //
+        row_width`` where ``packed`` (4 for dims 17 to 32, 2 for 33 to 64),
+        1 otherwise."""
+        return LANES // self.row_width if self.packed else 1
 
     @property
     def total_rows(self) -> int:
+        """The table's rows: the features' rows rounded up to ``row_pad``."""
         raw = int(self.row_offsets[-1])
-        pad = self.row_pad * self.rows_per_line
-        return -(-raw // pad) * pad
+        return -(-raw // self.row_pad) * self.row_pad
+
+    @property
+    def total_lines(self) -> int:
+        """A packed table's lines: enough for ``total_rows``, rounded up to
+        ``row_pad`` with lines of zeros."""
+        lines = -(-self.total_rows // self.rows_per_line)
+        return -(-lines // self.row_pad) * self.row_pad
 
     @property
     def max_pooling(self) -> int:
@@ -117,26 +134,50 @@ class EmbeddingConfig:
         return self.total_rows * self.dim * dtype_bytes
 
 
+def _row_width(dim: int) -> int:
+    """The power of two at or above ``dim``."""
+    return 1 << (dim - 1).bit_length()
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class LineTable:
-    """A ``[rows, dim]`` table stored ``k = 128 // dim`` rows to a line.
+    """A ``[num_rows, dim]`` table stored ``k`` rows to a 128-lane line.
 
-    ``lines`` is ``[rows // k, 128]``: line ``i`` holds rows ``k*i`` to
-    ``k*i + k - 1`` side by side, the row-major reshape of the rows. The
-    values are the rows' own; ``np.asarray`` gives them as ``[rows, dim]``.
+    Each row takes ``width`` lanes, ``dim`` rounded up to a power of two,
+    the lanes past ``dim`` exact zeros, and ``k = 128 // width``. ``lines``
+    is ``[L, 128]``: line ``i`` holds rows ``k*i`` to ``k*i + k - 1`` side
+    by side, and the lanes past the last row hold zeros (``L`` is the
+    config's ``total_lines``, at least ``num_rows / k``). The values are
+    the rows' own; ``np.asarray`` gives them as ``[num_rows, dim]``,
+    without the pad.
     """
 
     lines: jax.Array
     dim: int = dataclasses.field(metadata=dict(static=True))
+    num_rows: int = dataclasses.field(metadata=dict(static=True))
+
+    @classmethod
+    def pack(cls, rows: jax.Array, num_lines: int) -> LineTable:
+        """``rows`` ``[n, dim]`` stored in ``num_lines`` lines, each row
+        padded to ``width`` lanes, with zeros past them."""
+        n, dim = rows.shape
+        width = _row_width(dim)
+        padded = jnp.pad(rows, ((0, num_lines * (LANES // width) - n),
+                                (0, width - dim)))
+        return cls(padded.reshape(num_lines, LANES), dim, n)
+
+    @property
+    def width(self) -> int:
+        return _row_width(self.dim)
 
     @property
     def rows_per_line(self) -> int:
-        return LANES // self.dim
+        return LANES // self.width
 
     @property
     def shape(self) -> tuple[int, int]:
-        return (self.lines.shape[0] * self.rows_per_line, self.dim)
+        return (self.num_rows, self.dim)
 
     @property
     def dtype(self):
@@ -144,10 +185,19 @@ class LineTable:
 
     def rows(self) -> jax.Array:
         """The ``[rows, dim]`` table (a relayout: keep it out of steps)."""
-        return self.lines.reshape(self.shape)
+        return self.lines.reshape(-1, self.width)[: self.num_rows, : self.dim]
 
     def __array__(self, dtype=None, copy=None):
-        return np.asarray(self.lines, dtype).reshape(self.shape)
+        lines = np.asarray(self.lines, dtype)
+        return lines.reshape(-1, self.width)[: self.num_rows, : self.dim]
+
+    def __mul__(self, scale):
+        """The table times a scalar, as the rows it stands for would be."""
+        if jnp.ndim(scale) != 0:
+            return NotImplemented
+        return dataclasses.replace(self, lines=self.lines * scale)
+
+    __rmul__ = __mul__
 
 
 def _gather_lines(table: LineTable, row_ids: jax.Array) -> jax.Array:
@@ -165,15 +215,15 @@ def gather_rows(table, row_ids: jax.Array) -> jax.Array:
     the row with exact zeros), so the values are the stored ones."""
     if not isinstance(table, LineTable):
         return jnp.take(table, row_ids, axis=0)
-    k, dim = table.rows_per_line, table.dim
-    blocks = _gather_lines(table, row_ids).reshape(*row_ids.shape, k, dim)
+    k = table.rows_per_line
+    blocks = _gather_lines(table, row_ids).reshape(*row_ids.shape, k, table.width)
     pick = (row_ids % k)[..., None, None] == jnp.arange(k)[:, None]
-    return jnp.where(pick, blocks, 0).sum(axis=-2)
+    return jnp.where(pick, blocks[..., : table.dim], 0).sum(axis=-2)
 
 
 def init_embedding(key, cfg: EmbeddingConfig):
     """One combined [total_rows, dim] table, DLRM uniform init per table,
-    stored as a ``LineTable`` where ``cfg.rows_per_line > 1``."""
+    stored as a ``LineTable`` of ``cfg.total_lines`` where ``cfg.packed``."""
     # Init the whole combined table in one draw with a per-table scale:
     # equivalent in distribution to per-table U(-1/sqrt(V), 1/sqrt(V)).
     table = jax.random.uniform(
@@ -185,8 +235,8 @@ def init_embedding(key, cfg: EmbeddingConfig):
         v = cfg.vocab_sizes[f]
         scales[offsets[f] : offsets[f + 1]] = 1.0 / np.sqrt(v)
     table = (table * jnp.asarray(scales)).astype(cfg.dtype)
-    if cfg.rows_per_line > 1:
-        return {"table": LineTable(table.reshape(-1, LANES), cfg.dim)}
+    if cfg.packed:
+        return {"table": LineTable.pack(table, cfg.total_lines)}
     return {"table": table}
 
 
@@ -251,21 +301,21 @@ def _embedding_bag_lines(table: LineTable, ids: jax.Array,
     """EmbeddingBag over a ``LineTable``: gather whole lines, then pool.
 
     The pool keeps lane ``l`` of a looked-up line where the row sits at
-    lane block ``l // dim`` (and the id is not padding), sums the bag's
+    lane block ``l // width`` (and the id is not padding), sums the bag's
     lines, and folds the ``k`` lane blocks: only exact zeros are added to
     the rows' values, all on the vector unit (no matrix product).
     """
     B, F, P = ids.shape
-    k, dim = table.rows_per_line, cfg.dim
+    k, width, dim = table.rows_per_line, table.width, table.dim
     with jax.named_scope("gather"):
         row = _feature_row_index(cfg, ids)
         lines = _gather_lines(table, row)  # [B, F, P, 128]
 
     with jax.named_scope("pool"):
         valid = ids >= 0
-        keep = ((row % k)[..., None] == jnp.arange(LANES) // dim) & valid[..., None]
+        keep = ((row % k)[..., None] == jnp.arange(LANES) // width) & valid[..., None]
         pooled = jnp.where(keep, lines, 0).sum(axis=2)  # [B, F, 128]
-        pooled = pooled.reshape(B, F, k, dim).sum(axis=2)  # [B, F, dim]
+        pooled = pooled.reshape(B, F, k, width).sum(axis=2)[..., :dim]  # [B, F, dim]
         if cfg.combine == "mean":
             counts = jnp.maximum(valid.astype(table.dtype).sum(axis=2), 1.0)
             pooled = pooled / counts[..., None]

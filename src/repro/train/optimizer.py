@@ -111,7 +111,7 @@ def rowwise_adagrad(lr: float = 0.01, eps: float = 1e-8,
             p_new = _rows(p).astype(jnp.float32) - lr * g32 / (jnp.sqrt(a_new) + eps)
             p_new = p_new.astype(p.dtype)
             if isinstance(p, LineTable):
-                p_new = LineTable(p_new.reshape(p.lines.shape), p.dim)
+                p_new = LineTable.pack(p_new, p.lines.shape[0])
             return p_new, a_new
 
         flat = jax.tree_util.tree_map_with_path(

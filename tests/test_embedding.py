@@ -186,17 +186,35 @@ def _edge_ids(cfg, batch, seed):
     return jnp.asarray(ids.astype(np.int32))
 
 
+# dims that divide 128, and 12, 18 and 40, stored padded to 16, 32 and 64 lanes
+PACKED_DIMS = [8, 16, 32, 64, 12, 18, 40]
+
+
+def _width(dim):
+    return next(w for w in (8, 16, 32, 64, 128) if w >= dim)
+
+
+def _assert_pad_is_zero(table, rows):
+    """``table``'s lines hold ``rows`` at their width, zeros past them."""
+    stored = np.asarray(table.lines).reshape(-1, table.width)
+    n, dim = rows.shape
+    np.testing.assert_array_equal(stored[:n, :dim], rows)
+    assert not stored[:, dim:].any() and not stored[n:].any()
+
+
 @pytest.mark.parametrize("qr", [False, True], ids=["plain", "qr"])
 @pytest.mark.parametrize("combine", ["sum", "mean"])
-@pytest.mark.parametrize("dim", [8, 16, 32, 64])
+@pytest.mark.parametrize("dim", PACKED_DIMS)
 def test_packed_storage_pools_as_rows(dim, combine, qr):
     cfg = EmbeddingConfig(vocab_sizes=(50, 300, 7), dim=dim, pooling=(4, 3, 2),
                           combine=combine, row_pad=8, qr_buckets=16,
                           qr_features=(1,) if qr else ())
-    k = 128 // dim
-    assert cfg.rows_per_line == k and cfg.total_rows % (8 * k) == 0
+    k = 128 // _width(dim)
+    assert cfg.packed and cfg.rows_per_line == k and cfg.row_width == _width(dim)
+    assert cfg.total_lines % 8 == 0 and cfg.total_lines * k >= cfg.total_rows
     t = _lattice((cfg.total_rows, dim), dim)
-    packed = {"table": LineTable(jnp.asarray(t.reshape(-1, 128)), dim)}
+    packed = {"table": LineTable.pack(jnp.asarray(t), cfg.total_lines)}
+    _assert_pad_is_zero(packed["table"], t)
     ids = _edge_ids(cfg, 6, dim)
     got = embedding_bag_local(packed, ids, cfg)
     want = embedding_bag_local({"table": jnp.asarray(t)}, ids, cfg)
@@ -205,14 +223,15 @@ def test_packed_storage_pools_as_rows(dim, combine, qr):
         np.testing.assert_array_equal(got, _ref_bag(t, np.asarray(ids), cfg))
 
 
-@pytest.mark.parametrize("dim", [8, 16, 32, 64])
+@pytest.mark.parametrize("dim", PACKED_DIMS)
 def test_packed_init_holds_the_row_draw(dim):
-    """init packs the same [rows, dim] values, and gather_rows reads them back."""
+    """init packs the same [rows, dim] values, pad lanes and lines zero,
+    and gather_rows reads them back."""
     cfg = _cfg(vocabs=(50, 70), dim=dim, pooling=(2, 2))
     key = jax.random.PRNGKey(dim)
     table = init_embedding(key, cfg)["table"]
     assert isinstance(table, LineTable)
-    assert table.lines.shape == (cfg.total_rows // cfg.rows_per_line, 128)
+    assert table.lines.shape == (cfg.total_lines, 128)
     assert table.shape == (cfg.total_rows, dim)
     draw = np.asarray(jax.random.uniform(key, (cfg.total_rows, dim), minval=-1.0,
                                          maxval=1.0))
@@ -221,17 +240,44 @@ def test_packed_init_holds_the_row_draw(dim):
         scale[cfg.row_offsets[f]:cfg.row_offsets[f + 1]] = 1.0 / np.sqrt(v)
     rows = np.asarray(table)
     np.testing.assert_array_equal(rows, draw * scale)
-    np.testing.assert_array_equal(np.asarray(table.lines).reshape(rows.shape), rows)
+    _assert_pad_is_zero(table, rows)
     ids = jnp.asarray([[0, 1, 49], [50, 119, cfg.total_rows - 1]], jnp.int32)
     np.testing.assert_array_equal(gather_rows(table, ids), rows[np.asarray(ids)])
 
 
-@pytest.mark.parametrize("dim,dtype", [(18, jnp.float32), (1, jnp.float32),
+@pytest.mark.parametrize("vocabs, dim, rows, lines", [
+    ((1_000_000, 10_000, 1_000_000, 100_000), 18, 2_110_464, 527_872),
+    ((1_000_000, 10_000, 1_000_000, 100_000), 32, 2_110_464, 527_872),
+    ((1_000_000,) * 10, 32, 10_000_384, 2_500_096),
+], ids=["dien18", "dien32", "rmc1"])
+def test_total_rows_round_the_rows_to_row_pad(vocabs, dim, rows, lines):
+    """The rows are the features' rows rounded up to row_pad, as the
+    benchmark's references draw them, whatever the row width; the lines
+    that hold them are rounded up to row_pad with lines of zeros."""
+    cfg = EmbeddingConfig(vocab_sizes=vocabs, dim=dim, pooling=(1,) * len(vocabs))
+    assert cfg.total_rows == -(-sum(vocabs) // 512) * 512 == rows
+    assert cfg.total_lines == lines and lines % 512 == 0
+
+
+@pytest.mark.parametrize("dim", [18, 32])
+def test_scaled_line_table_scales_its_rows(dim):
+    """A LineTable times a scalar, inside jit or not, is the LineTable of
+    the scaled rows, its pad lanes still zero."""
+    cfg = _cfg(vocabs=(50, 70), dim=dim, pooling=(2, 2))
+    table = init_embedding(jax.random.PRNGKey(0), cfg)["table"]
+    for scaled in (table * np.float32(10.0), jax.jit(lambda t: t * np.float32(10.0))(table)):
+        assert isinstance(scaled, LineTable) and scaled.shape == table.shape
+        np.testing.assert_array_equal(np.asarray(scaled),
+                                      np.asarray(table) * np.float32(10.0))
+        _assert_pad_is_zero(scaled, np.asarray(scaled))
+
+
+@pytest.mark.parametrize("dim,dtype", [(1, jnp.float32),
                                        (128, jnp.float32), (32, jnp.bfloat16)],
-                         ids=["dim18", "dim1", "dim128", "bf16"])
+                         ids=["dim1", "dim128", "bf16"])
 def test_unpackable_tables_keep_rows(dim, dtype):
     cfg = _cfg(dim=dim, dtype=dtype)
-    assert cfg.rows_per_line == 1
+    assert not cfg.packed and cfg.rows_per_line == 1
     table = init_embedding(jax.random.PRNGKey(0), cfg)["table"]
     assert isinstance(table, jax.Array)
     assert table.shape == (cfg.total_rows, dim) and table.dtype == dtype
@@ -239,9 +285,11 @@ def test_unpackable_tables_keep_rows(dim, dtype):
     np.testing.assert_array_equal(gather_rows(table, ids), np.asarray(table)[[3, 0, 51]])
 
 
-def test_rowwise_adagrad_on_packed_rows_matches_rows():
-    """One accumulator per row whether the table is packed or not."""
-    cfg = _cfg(vocabs=(20,), dim=32, pooling=(3,))
+@pytest.mark.parametrize("dim", [32, 18])
+def test_rowwise_adagrad_on_packed_rows_matches_rows(dim):
+    """One accumulator per row, over its dim lanes, whether the table is
+    packed or not; the update keeps the pad lanes zero."""
+    cfg = _cfg(vocabs=(20,), dim=dim, pooling=(3,))
     packed = init_embedding(jax.random.PRNGKey(0), cfg)
     rows = {"table": jnp.asarray(np.asarray(packed["table"]))}
     ids = jnp.asarray([[[3, 5, 5]], [[7, -1, 4]]], jnp.int32)
@@ -259,3 +307,4 @@ def test_rowwise_adagrad_on_packed_rows_matches_rows():
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(s_packed["acc"]["table"], s_rows["acc"]["table"],
                                rtol=1e-6, atol=1e-9)
+    _assert_pad_is_zero(p_packed["table"], np.asarray(p_packed["table"]))

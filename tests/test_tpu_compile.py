@@ -144,8 +144,9 @@ def test_rmc1_serve_step_compiles_for_v5e(one_chip):
 
 def test_dien_serve_step_compiles_for_v5e(one_chip):
     cfg = dien(prod=False)
-    # 18-wide rows do not divide a 128-lane line: the table stays [rows, 18]
-    assert cfg.embedding.rows_per_line == 1
+    # 18-wide f32 rows are padded to 32 lanes and stored four to a line
+    emb = cfg.embedding
+    assert (emb.row_width, emb.rows_per_line, emb.total_lines) == (32, 4, 527872)
     params = jax.eval_shape(lambda: din.init(jax.random.PRNGKey(0), cfg))
     params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), params)
     batch = {k: _spec(s.shape, s.dtype, one_chip)
@@ -156,5 +157,20 @@ def test_dien_serve_step_compiles_for_v5e(one_chip):
         params, batch).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > cfg.embedding.bytes()
     # the gathers, both recurrences and the attention unit keep their names
-    paths = _scope_paths(compiled.as_text(), "dien.json")
+    hlo = compiled.as_text()
+    paths = _scope_paths(hlo, "dien.json")
     assert {"sparse", "dense/gru", "dense/attention", "dense/augru"} <= paths
+
+    # the table stays rows-major, and each lookup fetches one whole line
+    entry = _entry(hlo)
+    table = [ln for ln in entry if "parameter(" in ln and "embedding" in ln]
+    assert len(table) == 1 and "= f32[527872,128]{1,0:T(8,128)} parameter(" in table[0]
+    gathers = [ln for ln in hlo.splitlines() if " gather(" in ln and "sparse" in ln]
+    assert gathers and all("slice_sizes={1,128}" in ln for ln in gathers)
+    # no instruction copies, transposes or relayouts the table
+    moves = [re.search(r"= \(?\w+\[([\d,]*)\][^ ]* "
+                       r"(copy|copy-start|transpose|reshape)\(", ln) for ln in entry]
+    moves = [m for m in moves if m]
+    assert moves  # the pattern reads this compiler's text
+    for m in moves:
+        assert m.group(1) != "527872,128", m.string[:200]
