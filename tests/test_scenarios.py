@@ -241,6 +241,87 @@ class TestGoldenEquivalence:
 
 
 # ---------------------------------------------------------------------------
+# golden days: every registered day and one event-core day, pinned in JSON
+# ---------------------------------------------------------------------------
+#
+# Regenerate only when a day's output is meant to change:
+#   PYTHONPATH=src:tests JAX_PLATFORMS=cpu python -c \
+#       "import test_scenarios as t; t.write_golden_days()"
+
+GOLDEN_DAYS = pathlib.Path(__file__).with_name("golden_days.json")
+EVENT_CORE_DAY = "event_core_hedges"
+
+
+def _event_core_day():
+    """TestEventCoreDay's hedging day (tests/test_cluster_runtime.py): the
+    smoke topology at 9% load over 6 intervals, every arrival served and
+    stragglers hedged event-ordered."""
+    profiles = {n: paper_profile(n) for n in SMOKE_WORKLOADS}
+    servers = {s: SERVER_TYPES[s] for s in SMOKE_SERVERS}
+    table, records = build_table(profiles, servers, dict(SMOKE_AVAILABILITY))
+    cap = table.fleet_capacity()
+    traces = np.stack([diurnal_trace(0.09 * cap[m], seed=m, n_steps=6)
+                       for m in range(len(table.workloads))])
+    return simulate_cluster_day(
+        DayInputs(table=table, records=records, profiles=profiles,
+                  traces=traces, servers=servers, overprovision=0.3, seed=0),
+        config=RuntimeConfig(event_core=True, event_core_queries=40_000))
+
+
+def _day_record(out) -> dict:
+    """A served day as JSON: ``to_dict()``, which holds the ``series``
+    block and the ``events``; a geo day adds each region's whole day."""
+    rec = {"day": out.to_dict()}
+    if hasattr(out, "regions"):
+        rec["regions"] = {n: r.to_dict() for n, r in out.regions.items()}
+    return json.loads(json.dumps(
+        rec, default=lambda o: o.tolist() if hasattr(o, "tolist") else o))
+
+
+def _assert_matches_golden(got, want, path="day"):
+    """Ints, bools, strings, None and events exactly; floats to rtol 1e-9."""
+    if isinstance(want, dict):
+        assert isinstance(got, dict) and got.keys() == want.keys(), path
+        for k in want:
+            _assert_matches_golden(got[k], want[k], f"{path}.{k}")
+    elif isinstance(want, list):
+        assert isinstance(got, list) and len(got) == len(want), path
+        for i, (g, w) in enumerate(zip(got, want)):
+            _assert_matches_golden(g, w, f"{path}[{i}]")
+    elif isinstance(want, float):
+        assert isinstance(got, float), (path, got, want)
+        assert got == want or (np.isnan(got) and np.isnan(want)) \
+            or np.isclose(got, want, rtol=1e-9, atol=0.0), (path, got, want)
+    else:
+        assert type(got) is type(want) and got == want, (path, got, want)
+
+
+def write_golden_days(path=GOLDEN_DAYS):
+    """Serve every golden day in a throwaway profile cache and write them."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(profile_cache, "PROFILE_DIR", pathlib.Path(tempfile.mkdtemp()))
+    mp.setattr(sc, "_BUNDLES", {})
+    mp.setattr(sc, "_COLOC_TABLES", {})
+    try:
+        days = {n: _day_record(run_scenario(get_scenario(n)))
+                for n in registry()}
+        days[EVENT_CORE_DAY] = _day_record(_event_core_day())
+    finally:
+        mp.undo()
+    path.write_text(json.dumps(days, indent=1, sort_keys=True) + "\n")
+
+
+@pytest.mark.parametrize("name", sorted(sc._REGISTRY) + [EVENT_CORE_DAY])
+def test_day_matches_golden(name, zoo_days):
+    """Every registered day, and an event-core day with hedges, reproduce
+    the recorded output: the serving refactors must not move a number."""
+    want = json.loads(GOLDEN_DAYS.read_text())
+    assert set(want) == set(registry()) | {EVENT_CORE_DAY}
+    out = _event_core_day() if name == EVENT_CORE_DAY else zoo_days[name]
+    _assert_matches_golden(_day_record(out), want[name])
+
+
+# ---------------------------------------------------------------------------
 # spec construction, registry, and full_scale
 # ---------------------------------------------------------------------------
 
