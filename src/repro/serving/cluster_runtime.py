@@ -41,7 +41,7 @@ tail_ok=...)``).  See ``docs/cluster_serving.md``.
 from __future__ import annotations
 
 import dataclasses
-import warnings
+import functools
 
 import numpy as np
 
@@ -78,9 +78,6 @@ class RuntimeConfig:
     hedge_quantile: float = 0.99
     hedge_factor: float = 2.0
     sla_quantile: float = 0.95        # "meets SLA" = this quantile <= sla_ms
-    carry_backlog: bool = True        # continuous-time: carry pool state
-    hedge_live_queue: bool = True     # hedges join the alternate's live queue
-    tail_feedback: bool = True        # feed achieved tail into hysteresis
     # --- event core (exact full-interval mode, see event_core.py) ---------
     # event_core=True simulates every arrival of the interval (cap below,
     # not _PROBE_CAP), extends each measured window to the interval end so
@@ -391,8 +388,7 @@ class PairService:
                     s_end, self.dur_dense[inv], self.k, state["dense"])
         else:
             ends = self._accel(sub_ready, self.sub_s[sub], state)
-        cum0 = np.concatenate([[0], np.cumsum(counts)])
-        out[nz] = np.maximum.reduceat(ends, cum0[:-1][nz])
+        _reduce_queries(out, ends, counts, nz)
         return out
 
     def solo_time(self, qidx: np.ndarray) -> np.ndarray:
@@ -572,8 +568,412 @@ def failure_schedule(n_steps: int, n_servers: int, fail_prob: float,
 
 
 # ---------------------------------------------------------------------------
-# the day simulation
+# the day simulation, phase by phase
 # ---------------------------------------------------------------------------
+
+
+class _DayLog:
+    """What the phases record over one day: provisioning rows, per-(workload,
+    interval) measurements, carried slot states and events."""
+
+    def __init__(self, M: int, T: int):
+        self.power = np.zeros(T)                  # provisioned W incl. drain
+        self.capacity = np.zeros(T, np.int64)     # machines allocated
+        self.churn = np.zeros(T, np.int64)        # machines added + removed
+        self.co_cap = np.zeros(T, np.int64)       # shared machines
+        # per (workload, interval): latency seconds (None: not measured),
+        # residual work at window end, whether the window was bridged
+        self.lat_mt: list[list[np.ndarray | None]] = \
+            [[None] * T for _ in range(M)]
+        self.backlog_mt = np.zeros((M, T))
+        self.bridged_mt: list[list[bool | None]] = \
+            [[None] * T for _ in range(M)]
+        # per workload: residual slot states by (group, instance)
+        self.slot_states: list[dict[tuple, dict]] = [{} for _ in range(M)]
+        self.n_hedged = np.zeros(M, np.int64)
+        self.n_retried = np.zeros(M, np.int64)
+        self.events: list[str] = []
+        self.feasible = True
+        self.tail_ok = True                       # last interval met SLAs
+
+    def infeasible(self, event: str):
+        self.feasible = False
+        self.events.append(event)
+
+    def provision(self, prov: StatefulProvisioner, load: np.ndarray, t: int,
+                  policy: str):
+        """Phase 1: provision interval ``t`` (fed the last interval's
+        achieved tail) and record its power, capacity and churn."""
+        step = prov.step(load, tail_ok=self.tail_ok)
+        self.power[t] = step.power_w
+        self.capacity[t] = step.capacity
+        self.churn[t] = step.churn
+        if not step.feasible:
+            self.infeasible(f"t={t}: {policy} infeasible on surviving pool")
+        self.co_cap[t] = len(step.coalloc)
+        return step
+
+
+def _query_streams(inputs: DayInputs, cfg: RuntimeConfig,
+                   interval_s: float) -> tuple[SimCache, int]:
+    """The day's CRN query streams and the per-(workload, interval) cap on
+    the queries a window serves."""
+    query_sizes = inputs.query_sizes
+    if query_sizes is None:
+        from repro.core.efficiency import default_query_sizes
+        query_sizes = default_query_sizes()
+    cache = SimCache(query_sizes, inputs.seed)
+    if not cfg.event_core:
+        return cache, min(cfg.queries_per_interval, _PROBE_CAP)
+    cap_q = int(cfg.event_core_queries)
+    # grow the CRN streams once, up front, to the day's largest interval
+    # population: every window is then a bitwise prefix of the same streams
+    # and no PairService ever binds stale tables
+    cache.ensure(int(np.clip(float(inputs.traces.max()) * interval_s,
+                             64, cap_q)))
+    return cache, cap_q
+
+
+def _service_pool(inputs: DayInputs, cache: SimCache):
+    """``service(m, server, dilation)``: the day's :class:`PairService` of
+    workload ``m`` on ``server``, built once.  A tenant of a shared machine
+    gets its solo record with the duration tables dilated by the
+    co-resident set's interference factor."""
+    table, servers = inputs.table, inputs.servers or SERVER_TYPES
+
+    @functools.cache
+    def service(m: int, server: str, dilation: float = 1.0) -> PairService:
+        name = table.workloads[m]
+        return PairService(inputs.profiles[name], servers[server],
+                           inputs.records[f"{name}|{server}"], cache,
+                           dilation=dilation)
+    return service
+
+
+def _failure_victims(prov: StatefulProvisioner, table: EfficiencyTable,
+                     failures: list[tuple[int, int, float]], t: int,
+                     events: list[str]) -> dict[int, list[tuple]]:
+    """Phase 2: take interval ``t``'s failed machines out of the pool and map
+    them onto serving victims per workload, ``(group, frac)`` by the slot
+    pool's machine groups: the server type ``h`` of a solo machine, or
+    ``("c", server, tenants)`` of a shared one, whose loss hits every
+    tenant."""
+    victims_by_m: dict[int, list[tuple]] = {}
+    for (ft, fh, frac) in failures:
+        if ft != t:
+            continue
+        before = int(prov.avail[fh])
+        cells = prov.fail(fh)
+        if not cells:
+            if int(prov.avail[fh]) < before:
+                events.append(f"t={t}: spare {table.servers[fh]} failed")
+            continue
+        for v in cells:
+            if isinstance(v, tuple):
+                h, m = v
+                victims_by_m.setdefault(m, []).append((int(h), frac))
+                events.append(
+                    f"t={t}: serving {table.servers[h]} failed "
+                    f"({table.workloads[m]}) -> re-route + re-provision")
+            else:
+                g = ("c", v.server, v.tenants)
+                for name in v.tenants:
+                    victims_by_m.setdefault(
+                        table.workloads.index(name), []).append((g, frac))
+                events.append(
+                    f"t={t}: shared {v.server} failed "
+                    f"({'+'.join(v.tenants)}) -> re-route + re-provision")
+    return victims_by_m
+
+
+def _window(rate: float, t0: float, interval_s: float, unit_gaps: np.ndarray,
+            cap_q: int, event_core: bool):
+    """A workload's CRN arrivals in one interval, the window end and whether
+    a window short of the interval end is bridged by stationarity (the
+    event core measures it to the boundary instead)."""
+    n = int(np.clip(rate * interval_s, 64, cap_q))
+    arrivals = t0 + np.cumsum(unit_gaps[:n] * (1.0 / rate))
+    w_end = float(arrivals[-1])
+    bridged = bool(n == cap_q and w_end < t0 + interval_s)
+    if event_core:
+        w_end = max(w_end, t0 + interval_s)
+    return arrivals, w_end, bridged
+
+
+@dataclasses.dataclass
+class _SlotPool:
+    """One workload's router slots in one interval with, per slot, its
+    machine group, its service, its absolute pool state (updated in place
+    by the passes) and the key it carries out under (None: a draining or
+    failed machine)."""
+
+    slots: list[ServerSlot] = dataclasses.field(default_factory=list)
+    groups: list = dataclasses.field(default_factory=list)
+    pair_of: list[PairService] = dataclasses.field(default_factory=list)
+    states: list[dict] = dataclasses.field(default_factory=list)
+    keys: list[tuple | None] = dataclasses.field(default_factory=list)
+
+    def add_group(self, group, serving: list, keep: int, draining: list,
+                  t0: float, transitions: TransitionConfig,
+                  prev_states: dict):
+        """One machine group's slots: ``serving`` (the ``keep`` kept machines
+        first, then the added ones), then ``draining``.  Each entry is
+        ``(svc, server, rate, machine)``, or None for a machine that does
+        not serve this workload (it keeps its index).  Kept machines resume
+        their carried ``(group, i)`` state, added ones start idle once their
+        model is loaded, draining ones inherit their backlog and take
+        queries until ``drain_s``."""
+        for i, slot in enumerate(serving):
+            fresh = i >= keep
+            self._add(group, slot,
+                      None if fresh else prev_states.get((group, i)), t0,
+                      t0 + transitions.model_load_s if fresh else t0,
+                      float("inf"), (group, i))
+        for j, slot in enumerate(draining):
+            self._add(group, slot, prev_states.get((group, keep + j)), t0, t0,
+                      t0 + transitions.drain_s, None)
+
+    def _add(self, group, slot, residual: dict | None, t0: float,
+             ready: float, retire: float, key):
+        if slot is None:
+            return
+        svc, server, rate, machine = slot
+        self.slots.append(ServerSlot(server, rate, ready_at=ready,
+                                     retire_at=retire, machine=machine))
+        self.groups.append(group)
+        self.pair_of.append(svc)
+        self.states.append(_state_abs(
+            residual if residual is not None else svc.fresh_state(), t0))
+        self.keys.append(key)
+
+
+def _by_group(machines, name: str) -> dict[tuple, list]:
+    """The shared machines serving ``name``, by ("c", server, tenants)."""
+    out: dict[tuple, list] = {}
+    for c in machines:
+        if name in c.tenants:
+            out.setdefault(("c", c.server, c.tenants), []).append(c)
+    return out
+
+
+def _slot_pool(step, m: int, name: str, t0: float,
+               transitions: TransitionConfig, prev_states: dict, service,
+               server_names) -> _SlotPool:
+    """Phase 3: workload ``m``'s slot pool in one interval.  Solo machines
+    group by server type ``h``, in ``h`` order; shared machines follow, one
+    slot per tenant pool per machine, weighted by the tenant's rate and
+    grouped by ("c", server, tenants) in sorted order, their identity that
+    group plus the instance, so a hardware failure hits every tenant."""
+    pool = _SlotPool()
+    for h, server in enumerate(server_names):
+        cnt, rem = int(step.alloc[h, m]), int(step.removed[h, m])
+        if cnt + rem == 0:
+            continue
+        svc = service(m, server)
+        solo = (svc, server, svc.qps, None)
+        pool.add_group(h, [solo] * cnt, cnt - int(step.added[h, m]),
+                       [solo] * rem, t0, transitions, prev_states)
+
+    def shared(c, g: tuple, i: int):
+        rate_c = c.rate_of(name)
+        if rate_c <= 0.0:
+            return None
+        return (service(m, c.server, c.dilation_of(name)), c.server, rate_c,
+                g + (i,))
+
+    cur_g, rem_g, add_g = (_by_group(x, name) for x in (
+        step.coalloc, step.co_removed, step.co_added))
+    for g in sorted(set(cur_g) | set(rem_g)):
+        pend = list(add_g.get(g, []))
+        kept, fresh = [], []
+        for c in cur_g.get(g, []):
+            if c in pend:
+                pend.remove(c)
+                fresh.append(c)
+            else:
+                kept.append(c)
+        cur = kept + fresh
+        pool.add_group(g, [shared(c, g, i) for i, c in enumerate(cur)],
+                       len(kept), [shared(c, g, len(cur) + j)
+                                   for j, c in enumerate(rem_g.get(g, []))],
+                       t0, transitions, prev_states)
+    return pool
+
+
+def _retire_victims(pool: _SlotPool, victims: list[tuple],
+                    arrivals: np.ndarray) -> list[tuple[int, float]]:
+    """Phase 4a: each victim, the first slot of its machine group (a solo
+    server type ``h`` or a shared ``("c", server, tenants)``) taking queries
+    at its failure time, ``frac`` of the way through the window's arrivals,
+    stops there and carries nothing out.  Returns ``(slot, t_f)`` per
+    retired slot."""
+    fail_times: list[tuple[int, float]] = []
+    for (g, frac) in victims:
+        t_f = float(arrivals[0] + frac * (arrivals[-1] - arrivals[0]))
+        vi = next((i for i, s in enumerate(pool.slots)
+                   if pool.groups[i] == g and s.accepts(t_f)), None)
+        if vi is None:
+            continue
+        pool.slots[vi].retire_at = t_f
+        pool.keys[vi] = None
+        fail_times.append((vi, t_f))
+    return fail_times
+
+
+def _serve_window(router: QueryRouter, pool: _SlotPool, arrivals: np.ndarray,
+                  fail_times: list[tuple[int, float]], fleet: bool,
+                  log: _DayLog, tag: str):
+    """Phase 4b: route the window and serve it.  Failed slots go first:
+    queries they finish before the failure complete, the rest re-dispatch
+    to healthy slots at the detection time; then every slot serves its
+    stream in one pass.  Returns ``(assigned, latency, streams, n_retried)``
+    (``streams``: each slot's ``(queries, ready)``), or None if no slot was
+    ready."""
+    try:
+        assigned = router.assign_stream(arrivals)
+    except RuntimeError:
+        log.infeasible(f"{tag} had no ready servers in the window")
+        return None
+    ready = arrivals.copy()
+    latency = np.zeros(len(arrivals))
+    done = np.zeros(len(arrivals), bool)
+    n_retried = 0
+    for (vi, t_f) in fail_times:
+        qv = np.flatnonzero(assigned == vi)
+        if len(qv) == 0:
+            router.mark_failed(pool.slots[vi])
+            continue
+        # an earlier victim's retries may have landed here: FIFO order is
+        # by ready time, not stream index
+        qv = qv[np.argsort(ready[qv], kind="stable")]
+        f = pool.pair_of[vi].finish(qv, ready[qv], state=pool.states[vi])
+        ok = f <= t_f
+        latency[qv[ok]] = f[ok] - arrivals[qv[ok]]
+        done[qv[ok]] = True
+        router.mark_failed(pool.slots[vi])
+        lost = qv[~ok]
+        if len(lost):
+            ready[lost] = t_f
+            try:
+                assigned[lost] = router.assign_stream(ready[lost])
+                n_retried += len(lost)
+            except RuntimeError:
+                latency[lost] = np.inf
+                done[lost] = True
+                log.infeasible(f"{tag} lost queries — no healthy servers "
+                               "left to retry on")
+    streams: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+    jobs: list[tuple] = []
+    for si, svc in enumerate(pool.pair_of):
+        qs = np.flatnonzero((assigned == si) & ~done)
+        if len(qs):
+            qs = qs[np.argsort(ready[qs], kind="stable")]
+            jobs.append((svc, qs, ready[qs], pool.states[si]))
+            streams[si] = (qs, ready[qs])
+    for (_, qs, _, _), f in zip(jobs, _finish_many(jobs, fleet=fleet)):
+        latency[qs] = f - arrivals[qs]
+    return assigned, latency, streams, n_retried
+
+
+_NO_STREAM = (np.zeros(0, np.int64), np.zeros(0))
+
+
+def _hedge_first_pass(pool: _SlotPool, carry_in: list[dict],
+                      straggler: np.ndarray, alt: np.ndarray,
+                      arrivals: np.ndarray, latency: np.ndarray,
+                      streams: dict, thr: float) -> int:
+    """Phase 5, first-pass model: a duplicate issued at arrival + ``thr``
+    joins the alternate slot's live queue (its carried backlog plus its
+    in-window stream); first completion wins.  The primaries' first-pass
+    latencies stand, but the slot's carried state takes in the hedge work.
+    Updates ``latency`` and ``pool.states``; returns the queries sped up."""
+    n_hedged = 0
+    ok = alt >= 0
+    for a in np.unique(alt[ok]):
+        sel = straggler[ok & (alt == a)]
+        prim_q, prim_r = streams.get(a, _NO_STREAM)
+        mq = np.concatenate([prim_q, sel])
+        mr = np.concatenate([prim_r, arrivals[sel] + thr])
+        order = np.argsort(mr, kind="stable")
+        st = _state_copy(carry_in[a])
+        f_all = pool.pair_of[a].finish(mq[order], mr[order], state=st)
+        pos = np.empty(len(mq), np.int64)
+        pos[order] = np.arange(len(mq))
+        hedged = f_all[pos[len(prim_q):]] - arrivals[sel]
+        pool.states[a] = st
+        better = hedged < latency[sel]
+        latency[sel[better]] = hedged[better]
+        n_hedged += int(better.sum())
+    return n_hedged
+
+
+def _hedge_event_ordered(pool: _SlotPool, carry_in: list[dict],
+                         straggler: np.ndarray, alt: np.ndarray,
+                         arrivals: np.ndarray, latency: np.ndarray,
+                         streams: dict, thr: float) -> int:
+    """Phase 5, event core: each target slot re-serves its primaries with
+    the duplicates merged in at their issue times, so the primaries'
+    latencies now reflect the duplicates' admission; each straggler keeps
+    first-completion-wins.  Same contract as :func:`_hedge_first_pass`."""
+    ok = alt >= 0
+    hjobs, hmeta = [], []
+    for a in np.unique(alt[ok]):
+        sel = straggler[ok & (alt == a)]
+        prim_q, prim_r = streams.get(a, _NO_STREAM)
+        times, order = merge_event_streams(prim_r, arrivals[sel] + thr)
+        st = _state_copy(carry_in[a])
+        hjobs.append((pool.pair_of[a], np.concatenate([prim_q, sel])[order],
+                      times, st))
+        hmeta.append((a, sel, prim_q, order, st))
+    # apply all primary re-serves first, then the duplicate minima, so a
+    # straggler that is also a perturbed primary competes against its
+    # updated first-pass finish
+    dup_lat = []
+    for (a, sel, prim_q, order, st), f_all in zip(
+            hmeta, _finish_many(hjobs, fleet=True)):
+        pos = np.empty(len(order), np.int64)
+        pos[order] = np.arange(len(order))
+        latency[prim_q] = f_all[pos[:len(prim_q)]] - arrivals[prim_q]
+        dup_lat.append(f_all[pos[len(prim_q):]] - arrivals[sel])
+        pool.states[a] = st
+    n_hedged = 0
+    for (_, sel, _, _, _), hedged in zip(hmeta, dup_lat):
+        better = hedged < latency[sel]
+        latency[sel[better]] = hedged[better]
+        n_hedged += int(better.sum())
+    return n_hedged
+
+
+def _carry_out(states: list[dict], keys: list[tuple | None],
+               w_end: float) -> tuple[dict[tuple, dict], float]:
+    """Phase 6: machines that served through the window keep their residual
+    backlog under a compacted instance index per group (failed and
+    draining slots carry nothing).  Returns the carried states and the
+    interval's backlog in seconds of residual work."""
+    new_states: dict[tuple, dict] = {}
+    counters: dict = {}
+    bl = 0.0
+    for st, key in zip(states, keys):
+        if key is None:
+            continue
+        idx = counters.get(key[0], 0)
+        counters[key[0]] = idx + 1
+        bl += _drain_horizon(st, w_end)
+        new_states[(key[0], idx)] = _state_residual(st, w_end)
+    return new_states, bl
+
+
+def _tail_met(lat_t: list[np.ndarray | None], slas: list[float],
+              quantile: float) -> bool:
+    """Phase 7: whether every workload measured this interval met its SLA
+    quantile (a lost query, of infinite latency, fails it)."""
+    for lat, sla in zip(lat_t, slas):
+        if lat is None:
+            continue
+        if not np.isfinite(lat).all() or \
+                float(np.quantile(lat, quantile)) * 1e3 > sla:
+            return False
+    return True
 
 
 def _percentiles(lat_ms: np.ndarray) -> tuple[float, float, float]:
@@ -581,20 +981,76 @@ def _percentiles(lat_ms: np.ndarray) -> tuple[float, float, float]:
     return float(p50), float(p95), float(p99)
 
 
-def simulate_cluster_day(
-    inputs: DayInputs | EfficiencyTable,
-    records: dict[str, dict] | None = None,
-    profiles: dict[str, ModelProfile] | None = None,
-    traces: np.ndarray | None = None,   # [M, T] per-workload diurnal loads
-    policy: str = "hercules",
-    servers: dict[str, DeviceProfile] | None = None,
-    overprovision: float = 0.05,
-    transitions: TransitionConfig | None = None,
-    config: RuntimeConfig | None = None,
-    failures: list[tuple[int, int, float]] | None = None,
-    query_sizes: np.ndarray | None = None,
-    seed: int = 0,
-) -> DayResult:
+def _workload_day(lats: list[np.ndarray | None], sla: float,
+                  quantile: float) -> tuple[dict, dict]:
+    """Phase 8 for one workload: its day figures and its per-interval
+    (Fig. 8b-style) series."""
+    measured = [lat for lat in lats if lat is not None]
+    lat_ms = np.concatenate(measured) * 1e3 if measured else \
+        np.array([np.inf])
+    p50, p95, p99 = _percentiles(lat_ms)
+    q = float(np.quantile(lat_ms, quantile))
+    s: dict[str, list] = {k: [] for k in (
+        "p50_ms", "p95_ms", "p99_ms", "sla_attainment", "meets_sla",
+        "n_queries")}
+    met_t = 0
+    for lat in lats:
+        if lat is None:
+            for k in s:
+                s[k].append(None)
+            continue
+        ms = lat * 1e3
+        for k, v in zip(("p50_ms", "p95_ms", "p99_ms"), _percentiles(ms)):
+            s[k].append(v)
+        s["sla_attainment"].append(float(np.mean(ms <= sla)))
+        im = bool(float(np.quantile(ms, quantile)) <= sla)
+        s["meets_sla"].append(im)
+        s["n_queries"].append(int(len(ms)))
+        met_t += im
+    return {
+        "sla_ms": sla, "p50_ms": p50, "p95_ms": p95, "p99_ms": p99,
+        "sla_attainment": float(np.mean(lat_ms <= sla)),
+        "meets_sla": bool(q <= sla),
+        "interval_sla_met_frac":
+            float(met_t / len(measured)) if measured else 0.0,
+        "n_queries": int(len(lat_ms)),
+    }, s
+
+
+def _day_result(log: _DayLog, prov: StatefulProvisioner, policy: str,
+                names, slas: list[float], interval_s: float,
+                cfg: RuntimeConfig, colocated: bool) -> DayResult:
+    """Phase 8: the day-level aggregates, the ``series`` block and the
+    :class:`DayResult`."""
+    workloads, series = {}, {}
+    for m, name in enumerate(names):
+        figures, s = _workload_day(log.lat_mt[m], slas[m], cfg.sla_quantile)
+        s["backlog_s"] = [float(b) for b in log.backlog_mt[m]]
+        # True = stationarity-bridged past the query cap; False = fully
+        # simulated; None = interval not measured
+        s["bridged"] = log.bridged_mt[m]
+        series[name] = s
+        workloads[name] = {**figures, "n_hedged": int(log.n_hedged[m]),
+                           "n_retried": int(log.n_retried[m])}
+    return DayResult(
+        policy=policy, power=log.power, capacity=log.capacity,
+        churn=log.churn, feasible=log.feasible,
+        peak_power_w=float(log.power.max()),
+        avg_power_w=float(log.power.mean()),
+        peak_capacity=int(log.capacity.max()),
+        avg_capacity=float(log.capacity.mean()),
+        resolves=prov.n_resolves, holds=prov.n_holds,
+        tail_resolves=prov.n_tail_resolves,
+        total_churn=int(log.churn.sum()), per_workload=workloads,
+        series={"interval_s": interval_s, "per_workload": series},
+        all_meet_sla=all(w["meets_sla"] for w in workloads.values()),
+        events=log.events,
+        latencies=log.lat_mt if cfg.collect_latencies else None,
+        co_capacity=log.co_cap if colocated else None)
+
+
+def simulate_cluster_day(inputs: DayInputs, policy: str = "hercules",
+                         config: RuntimeConfig | None = None) -> DayResult:
     """Serve a full diurnal day at query granularity, continuous in time.
 
     ``inputs`` is a :class:`DayInputs` (``table``/``records`` from
@@ -605,534 +1061,76 @@ def simulate_cluster_day(
     per-workload latency percentiles and SLA attainment — the numbers
     ``provision_day`` only asserts via the QPS column — plus a per-interval
     ``series`` block (the Fig. 8b-style SLA-over-the-day record) and the
-    carried-backlog trajectory.
-
-    The pre-``DayInputs`` 13-argument call (table/records/profiles/traces
-    passed loose) still works but raises a :class:`DeprecationWarning`; it
-    wraps the arguments into a ``DayInputs`` and is bit-identical to the
-    bundled call (pinned by ``tests/test_geo.py``).
+    carried-backlog trajectory.  Each interval runs the phases above in
+    their order, the tail check last.
     """
-    if not isinstance(inputs, DayInputs):
-        warnings.warn(
-            "simulate_cluster_day(table, records, profiles, traces, ...) is "
-            "deprecated; bundle the day into DayInputs and call "
-            "simulate_cluster_day(inputs, policy=..., config=...)",
-            DeprecationWarning, stacklevel=2)
-        inputs = DayInputs(
-            table=inputs, records=records, profiles=profiles, traces=traces,
-            servers=servers, overprovision=overprovision,
-            transitions=transitions, failures=failures,
-            query_sizes=query_sizes, seed=seed)
-    table, records, profiles = inputs.table, inputs.records, inputs.profiles
-    traces = inputs.traces
-    overprovision = inputs.overprovision
-    failures = inputs.failures
-    seed = inputs.seed
-    servers = inputs.servers or SERVER_TYPES
     cfg = config or RuntimeConfig()
+    table, traces = inputs.table, inputs.traces
     transitions = inputs.transitions or TransitionConfig()
-    query_sizes = inputs.query_sizes
-    if query_sizes is None:
-        from repro.core.efficiency import default_query_sizes
-        query_sizes = default_query_sizes()
     M, T = traces.shape
-    H = len(table.servers)
-    cache = SimCache(query_sizes, seed)
-    if cfg.event_core:
-        cap_q = int(cfg.event_core_queries)
-        # grow the CRN streams once, up front, to the day's largest
-        # interval population: every window is then a bitwise prefix of
-        # the same streams and no PairService ever binds stale tables
-        n_max = int(np.clip(float(traces.max()) * transitions.interval_s,
-                            64, cap_q))
-        cache.ensure(n_max)
-    else:
-        cap_q = min(cfg.queries_per_interval, _PROBE_CAP)
-    services: dict[tuple[int, int], PairService] = {}
-
-    def service(h: int, m: int) -> PairService:
-        key = (h, m)
-        if key not in services:
-            rec = records[f"{table.workloads[m]}|{table.servers[h]}"]
-            services[key] = PairService(
-                profiles[table.workloads[m]], servers[table.servers[h]],
-                rec, cache)
-        return services[key]
-
-    # shared-machine services: the tenant's solo record with its duration
-    # tables dilated by the co-resident set's interference factor, keyed
-    # separately so solo services stay untouched
-    co_services: dict[tuple, PairService] = {}
-
-    def co_service(m: int, c) -> PairService:
-        name = table.workloads[m]
-        f = c.dilation_of(name)
-        key = (c.server, c.tenants, m, f)
-        if key not in co_services:
-            rec = records[f"{name}|{c.server}"]
-            co_services[key] = PairService(
-                profiles[name], servers[c.server], rec, cache, dilation=f)
-        return co_services[key]
-
-    prov = StatefulProvisioner(table, policy, overprovision, transitions,
-                               seed=seed, colocation=inputs.colocation)
+    cache, cap_q = _query_streams(inputs, cfg, transitions.interval_s)
+    service = _service_pool(inputs, cache)
+    prov = StatefulProvisioner(table, policy, inputs.overprovision,
+                               transitions, seed=inputs.seed,
+                               colocation=inputs.colocation)
     routers = [QueryRouter([], hedge_quantile=cfg.hedge_quantile,
-                           hedge_factor=cfg.hedge_factor, seed=seed + m)
+                           hedge_factor=cfg.hedge_factor, seed=inputs.seed + m)
                for m in range(M)]
-    fail_by_t: dict[int, list[tuple[int, float]]] = {}
-    for (ft, fh, frac) in failures or []:
-        fail_by_t.setdefault(ft, []).append((fh, frac))
-
-    power = np.zeros(T)
-    capacity = np.zeros(T, np.int64)
-    churn = np.zeros(T, np.int64)
-    co_cap = np.zeros(T, np.int64)
-    events: list[str] = []
-    feasible = True
-    # per-(workload, interval) latency arrays (None = not measured) and the
-    # carried-backlog trajectory (seconds of residual work at window end)
-    lat_mt: list[list[np.ndarray | None]] = [[None] * T for _ in range(M)]
-    backlog_mt = np.zeros((M, T))
-    # per-workload residual slot states keyed by (server type, instance)
-    slot_states: list[dict[tuple[int, int], dict]] = [{} for _ in range(M)]
-    n_hedged = np.zeros(M, np.int64)
-    n_retried = np.zeros(M, np.int64)
-    bridged_mt: list[list] = [[None] * T for _ in range(M)]
-    tail_ok_prev = True
+    slas = [inputs.profiles[name].sla_ms for name in table.workloads]
+    log = _DayLog(M, T)
 
     for t in range(T):
-        step = prov.step(traces[:, t], tail_ok=tail_ok_prev)
-        power[t] = step.power_w
-        capacity[t] = step.capacity
-        churn[t] = step.churn
-        if not step.feasible:
-            feasible = False
-            events.append(f"t={t}: {policy} infeasible on surviving pool")
+        step = log.provision(prov, traces[:, t], t, policy)
         t0 = t * transitions.interval_s
-        co_cap[t] = len(step.coalloc)
-        # map this interval's failures onto serving victims: solo (h, m)
-        # cells, or a shared CoMachine whose loss hits every tenant
-        victims_by_m: dict[int, list[tuple]] = {}
-        for (fh, frac) in fail_by_t.get(t, []):
-            before = int(prov.avail[fh])
-            cells = prov.fail(fh)
-            if not cells:
-                if int(prov.avail[fh]) < before:
-                    events.append(
-                        f"t={t}: spare {table.servers[fh]} failed")
-                continue
-            for v in cells:
-                if isinstance(v, tuple):
-                    h, m = v
-                    victims_by_m.setdefault(m, []).append((h, frac))
-                    events.append(
-                        f"t={t}: serving {table.servers[h]} failed "
-                        f"({table.workloads[m]}) -> re-route + re-provision")
-                else:  # shared machine: every tenant pool loses its view
-                    g = ("c", v.server, v.tenants)
-                    for name in v.tenants:
-                        victims_by_m.setdefault(
-                            table.workloads.index(name), []).append((g, frac))
-                    events.append(
-                        f"t={t}: shared {v.server} failed "
-                        f"({'+'.join(v.tenants)}) -> re-route + re-provision")
-
-        for m in range(M):
+        victims = _failure_victims(prov, table, inputs.failures or [], t,
+                                   log.events)
+        for m, name in enumerate(table.workloads):
             rate = float(traces[m, t])
             if rate <= 0.0:
-                slot_states[m] = {}  # a whole idle interval drains the pool
+                log.slot_states[m] = {}  # a whole idle interval drains it
                 continue
             if step.alloc[:, m].sum() == 0 and not any(
-                    table.workloads[m] in c.tenants
-                    and c.rate_of(table.workloads[m]) > 0.0
+                    name in c.tenants and c.rate_of(name) > 0.0
                     for c in step.coalloc):
-                feasible = False
-                slot_states[m] = {}
-                events.append(f"t={t}: {table.workloads[m]} unallocated")
+                log.infeasible(f"t={t}: {name} unallocated")
+                log.slot_states[m] = {}
                 continue
-            n = int(np.clip(rate * transitions.interval_s, 64, cap_q))
-            arrivals = t0 + np.cumsum(cache.unit_gaps[:n] * (1.0 / rate))
-            span = float(arrivals[-1] - arrivals[0])
-            w_end = float(arrivals[-1])
-            # a window that did not reach the interval end is bridged by
-            # stationarity (the historical approximation); the event core
-            # instead measures to the interval boundary so carried backlog
-            # sees the real inter-window drain
-            bridged_mt[m][t] = bool(n == cap_q
-                                    and w_end < t0 + transitions.interval_s)
-            if cfg.event_core:
-                w_end = max(w_end, t0 + transitions.interval_s)
-
-            # build the slot pool; each serving machine keeps a stable
-            # (type, instance) identity so its backlog carries across
-            # intervals — removed machines become draining slots that
-            # inherit (and finish) their backlog, added ones start idle
-            prev_states = slot_states[m] if cfg.carry_backlog else {}
-            slots: list[ServerSlot] = []
-            pair_of: list[PairService] = []
-            states: list[dict] = []      # absolute, updated by the passes
-            keys: list[tuple[int, int] | None] = []  # None = no carry-out
-            for h in range(H):
-                cnt = int(step.alloc[h, m])
-                add = int(step.added[h, m])
-                rem = int(step.removed[h, m])
-                if cnt + rem == 0:
-                    continue
-                svc = service(h, m)
-                keep = cnt - add
-                for i in range(cnt):
-                    ready = t0 + transitions.model_load_s \
-                        if i >= keep else t0
-                    slots.append(ServerSlot(table.servers[h], svc.qps,
-                                            ready_at=ready))
-                    pair_of.append(svc)
-                    res = prev_states.get((h, i)) if i < keep else None
-                    states.append(_state_abs(
-                        res if res is not None else svc.fresh_state(), t0))
-                    keys.append((h, i))
-                for j in range(rem):  # draining: serves until the deadline
-                    slots.append(ServerSlot(
-                        table.servers[h], svc.qps, ready_at=t0,
-                        retire_at=t0 + transitions.drain_s))
-                    pair_of.append(svc)
-                    res = prev_states.get((h, keep + j))
-                    states.append(_state_abs(
-                        res if res is not None else svc.fresh_state(), t0))
-                    keys.append(None)
-            # shared (co-located) machines: one slot per tenant pool per
-            # machine, weighted by the tenant's assigned rate and carrying
-            # a composite ("c", server, tenants, i) machine identity so a
-            # hardware failure correlates across every tenant it serves
-            name_m = table.workloads[m]
-            co_cur: dict[tuple, list] = {}
-            for c in step.coalloc:
-                if name_m in c.tenants:
-                    co_cur.setdefault(("c", c.server, c.tenants),
-                                      []).append(c)
-            co_rem: dict[tuple, list] = {}
-            for c in step.co_removed:
-                if name_m in c.tenants:
-                    co_rem.setdefault(("c", c.server, c.tenants),
-                                      []).append(c)
-            co_add: dict[tuple, list] = {}
-            for c in step.co_added:
-                if name_m in c.tenants:
-                    co_add.setdefault(("c", c.server, c.tenants),
-                                      []).append(c)
-            for g in sorted(set(co_cur) | set(co_rem)):
-                cur = co_cur.get(g, [])
-                # kept machines first: they map onto carried (g, i) states,
-                # newly added ones load their model before serving
-                pend = list(co_add.get(g, []))
-                kept_c, fresh_c = [], []
-                for c in cur:
-                    if c in pend:
-                        pend.remove(c)
-                        fresh_c.append(c)
-                    else:
-                        kept_c.append(c)
-                cur = kept_c + fresh_c
-                keep = len(kept_c)
-                for i, c in enumerate(cur):
-                    rate_c = c.rate_of(name_m)
-                    if rate_c <= 0.0:
-                        continue
-                    svc = co_service(m, c)
-                    ready = t0 + transitions.model_load_s \
-                        if i >= keep else t0
-                    slots.append(ServerSlot(c.server, rate_c,
-                                            ready_at=ready,
-                                            machine=g + (i,)))
-                    pair_of.append(svc)
-                    res = prev_states.get((g, i)) if i < keep else None
-                    states.append(_state_abs(
-                        res if res is not None else svc.fresh_state(), t0))
-                    keys.append((g, i))
-                for j, c in enumerate(co_rem.get(g, [])):
-                    rate_c = c.rate_of(name_m)
-                    if rate_c <= 0.0:
-                        continue
-                    svc = co_service(m, c)
-                    slots.append(ServerSlot(
-                        c.server, rate_c, ready_at=t0,
-                        retire_at=t0 + transitions.drain_s,
-                        machine=g + (len(cur) + j,)))
-                    pair_of.append(svc)
-                    res = prev_states.get((g, keep + j))
-                    states.append(_state_abs(
-                        res if res is not None else svc.fresh_state(), t0))
-                    keys.append(None)
+            arrivals, w_end, log.bridged_mt[m][t] = _window(
+                rate, t0, transitions.interval_s, cache.unit_gaps, cap_q,
+                cfg.event_core)
+            pool = _slot_pool(step, m, name, t0, transitions,
+                              log.slot_states[m], service, table.servers)
             router = routers[m]
-            router.refresh(slots)
+            router.refresh(pool.slots)
             thr = router.hedge_threshold()
-            carry_in = [_state_copy(st) for st in states] \
-                if cfg.hedge_live_queue and np.isfinite(thr) else None
-
-            # mid-window failures: victim stops taking queries at t_f.
-            # A tuple key is a shared machine's identity — every tenant
-            # pool retires the same machine index; an int key is a solo
-            # server type (shared slots are excluded from its match)
-            fail_times: list[tuple[int, float]] = []
-            for (h, frac) in victims_by_m.get(m, []):
-                t_f = float(arrivals[0] + frac * span)
-                if isinstance(h, tuple):
-                    vi = next((i for i, s in enumerate(slots)
-                               if s.machine is not None
-                               and s.machine[:3] == h
-                               and s.accepts(t_f)), None)
-                else:
-                    vi = next((i for i, s in enumerate(slots)
-                               if s.machine is None
-                               and s.server_type == table.servers[h]
-                               and s.accepts(t_f)), None)
-                if vi is None:
-                    continue
-                slots[vi].retire_at = t_f
-                keys[vi] = None          # a dead machine carries nothing
-                fail_times.append((vi, t_f))
-
-            try:
-                assigned = router.assign_stream(arrivals)
-            except RuntimeError:
-                feasible = False
-                slot_states[m] = {}
-                events.append(f"t={t}: {table.workloads[m]} had no ready "
-                              "servers in the window")
+            hedging = bool(np.isfinite(thr)) and len(pool.slots) > 1
+            # the slots' states entering the window: a hedge target's
+            # merged pass re-serves its stream from there
+            carry_in = [_state_copy(st) for st in pool.states] \
+                if hedging else None
+            fail_times = _retire_victims(pool, victims.get(m, []), arrivals)
+            served = _serve_window(router, pool, arrivals, fail_times,
+                                   cfg.event_core, log, f"t={t}: {name}")
+            if served is None:
+                log.slot_states[m] = {}
                 continue
-            ready = arrivals.copy()
-            latency = np.zeros(n)
-            done = np.zeros(n, bool)
-
-            # failed slots first: finished-before-failure queries complete,
-            # the rest re-dispatch to healthy slots at the detection time
-            for (vi, t_f) in fail_times:
-                qv = np.flatnonzero(assigned == vi)
-                if len(qv) == 0:
-                    router.mark_failed(slots[vi])
-                    continue
-                # an earlier victim's retries may have landed here: FIFO
-                # order is by ready time, not stream index
-                qv = qv[np.argsort(ready[qv], kind="stable")]
-                f = pair_of[vi].finish(qv, ready[qv], state=states[vi])
-                ok = f <= t_f
-                latency[qv[ok]] = f[ok] - arrivals[qv[ok]]
-                done[qv[ok]] = True
-                router.mark_failed(slots[vi])
-                lost = qv[~ok]
-                if len(lost):
-                    ready[lost] = t_f
-                    try:
-                        assigned[lost] = router.assign_stream(ready[lost])
-                        n_retried[m] += len(lost)
-                    except RuntimeError:
-                        feasible = False
-                        latency[lost] = np.inf
-                        done[lost] = True
-                        events.append(
-                            f"t={t}: {table.workloads[m]} lost queries — "
-                            "no healthy servers left to retry on")
-
-            streams: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-            jobs: list[tuple] = []
-            job_q: list[np.ndarray] = []
-            for si, svc in enumerate(pair_of):
-                qs = np.flatnonzero((assigned == si) & ~done)
-                if len(qs) == 0:
-                    continue
-                order = np.argsort(ready[qs], kind="stable")
-                qs = qs[order]
-                jobs.append((svc, qs, ready[qs], states[si]))
-                job_q.append(qs)
-                streams[si] = (qs, ready[qs])
-            for qs, f in zip(job_q,
-                             _finish_many(jobs, fleet=cfg.event_core)):
-                latency[qs] = f - arrivals[qs]
-                done[qs] = True
-
-            # straggler hedging: a duplicate issued at arrival + threshold
-            # is admitted into the alternate slot's live queue — it rides
-            # the slot's carried backlog plus its in-window stream, so a
-            # busy alternate cannot complete the hedge faster than its own
-            # queue allows (first completion wins)
-            if np.isfinite(thr) and len(slots) > 1:
-                straggler, t_issue, alt = router.hedge_events(
+            assigned, latency, streams, n_retried = served
+            log.n_retried[m] += n_retried
+            if hedging:
+                straggler, _, alt = router.hedge_events(
                     assigned, arrivals, latency, thr)
-                ok = alt >= 0
-                if len(straggler) and cfg.event_core \
-                        and carry_in is not None:
-                    # event-ordered pass: one merged re-simulation per
-                    # target slot, duplicates interleaved into the slot's
-                    # primary stream at their issue times.  The target's
-                    # own primaries are re-served in that order, so their
-                    # latencies now REFLECT the duplicate's admission
-                    # (the exact coupling the first-pass model bridges);
-                    # each straggler keeps first-completion-wins.
-                    alts = np.unique(alt[ok])
-                    hjobs, hmeta = [], []
-                    for a in alts:
-                        sel = straggler[ok & (alt == a)]
-                        ti = arrivals[sel] + thr
-                        prim_q, prim_r = streams.get(
-                            a, (np.zeros(0, np.int64), np.zeros(0)))
-                        times, order = merge_event_streams(prim_r, ti)
-                        mq = np.concatenate([prim_q, sel])[order]
-                        st = _state_copy(carry_in[a])
-                        hjobs.append((pair_of[a], mq, times, st))
-                        hmeta.append((a, sel, prim_q, order, st))
-                    fins = _finish_many(hjobs, fleet=True)
-                    # apply all primary re-serves first, then the
-                    # duplicate minima, so a straggler that is also a
-                    # perturbed primary competes against its updated
-                    # first-pass finish
-                    dup_lat = []
-                    for (a, sel, prim_q, order, st), f_all in zip(hmeta,
-                                                                  fins):
-                        pos = np.empty(len(order), np.int64)
-                        pos[order] = np.arange(len(order))
-                        latency[prim_q] = \
-                            f_all[pos[:len(prim_q)]] - arrivals[prim_q]
-                        dup_lat.append(f_all[pos[len(prim_q):]]
-                                       - arrivals[sel])
-                        states[a] = st
-                    for (a, sel, _, _, _), hedged in zip(hmeta, dup_lat):
-                        better = hedged < latency[sel]
-                        latency[sel[better]] = hedged[better]
-                        n_hedged[m] += int(better.sum())
-                elif len(straggler):
-                    for a in np.unique(alt[ok]):
-                        sel = straggler[ok & (alt == a)]
-                        ti = arrivals[sel] + thr
-                        if carry_in is not None:
-                            prim_q, prim_r = streams.get(
-                                a, (np.zeros(0, np.int64), np.zeros(0)))
-                            mq = np.concatenate([prim_q, sel])
-                            mr = np.concatenate([prim_r, ti])
-                            order = np.argsort(mr, kind="stable")
-                            st = _state_copy(carry_in[a])
-                            f_all = pair_of[a].finish(mq[order], mr[order],
-                                                      state=st)
-                            pos = np.empty(len(mq), np.int64)
-                            pos[order] = np.arange(len(mq))
-                            hedged = f_all[pos[len(prim_q):]] - arrivals[sel]
-                            # the merged pass re-serves the primaries too;
-                            # their first-pass latencies stand (duplicates
-                            # are a tail mechanism, not extra accounting),
-                            # but the slot's carried state now includes the
-                            # hedge work it actually absorbed
-                            states[a] = st
-                        else:  # legacy optimistic model: unloaded service
-                            hedged = (ti - arrivals[sel]) + \
-                                pair_of[a].solo_time(sel)
-                        better = hedged < latency[sel]
-                        latency[sel[better]] = hedged[better]
-                        n_hedged[m] += int(better.sum())
+                if len(straggler):
+                    hedge = _hedge_event_ordered if cfg.event_core \
+                        else _hedge_first_pass
+                    log.n_hedged[m] += hedge(pool, carry_in, straggler, alt,
+                                             arrivals, latency, streams, thr)
             router.observe_many(latency[np.isfinite(latency)])
-            lat_mt[m][t] = latency
+            log.lat_mt[m][t] = latency
+            log.slot_states[m], log.backlog_mt[m, t] = _carry_out(
+                pool.states, pool.keys, w_end)
+        log.tail_ok = _tail_met([log.lat_mt[m][t] for m in range(M)], slas,
+                                cfg.sla_quantile)
 
-            # carry-out: serving machines that survived the window keep
-            # their residual backlog under a compacted instance index (a
-            # failed machine's slot disappears; draining slots retire)
-            new_states: dict[tuple[int, int], dict] = {}
-            counters: dict[int, int] = {}
-            bl = 0.0
-            for si, key in enumerate(keys):
-                if key is None:
-                    continue
-                h = key[0]
-                idx = counters.get(h, 0)
-                counters[h] = idx + 1
-                bl += _drain_horizon(states[si], w_end)
-                new_states[(h, idx)] = _state_residual(states[si], w_end)
-            backlog_mt[m, t] = bl
-            slot_states[m] = new_states if cfg.carry_backlog else {}
-
-        # achieved-tail feedback for the next provisioning decision
-        if cfg.tail_feedback:
-            ok = True
-            for m in range(M):
-                lat = lat_mt[m][t]
-                if lat is None:
-                    continue
-                if not np.isfinite(lat).all():
-                    ok = False
-                    break
-                sla = profiles[table.workloads[m]].sla_ms
-                if float(np.quantile(lat, cfg.sla_quantile)) * 1e3 > sla:
-                    ok = False
-                    break
-            tail_ok_prev = ok
-
-    # day-level aggregates + the per-interval (Fig. 8b-style) series
-    workloads = {}
-    series: dict[str, dict] = {}
-    all_meet = True
-    for m, name in enumerate(table.workloads):
-        sla = profiles[name].sla_ms
-        measured = [lat for lat in lat_mt[m] if lat is not None]
-        lat_ms = np.concatenate(measured) * 1e3 if measured else \
-            np.array([np.inf])
-        p50, p95, p99 = _percentiles(lat_ms)
-        q = float(np.quantile(lat_ms, cfg.sla_quantile))
-        attainment = float(np.mean(lat_ms <= sla))
-        meets = q <= sla
-        all_meet &= meets
-        s: dict[str, list] = {k: [] for k in (
-            "p50_ms", "p95_ms", "p99_ms", "sla_attainment", "meets_sla",
-            "n_queries")}
-        met_t = 0
-        for t in range(T):
-            lat = lat_mt[m][t]
-            if lat is None:
-                for k in s:
-                    s[k].append(None)
-                continue
-            ms = lat * 1e3
-            i50, i95, i99 = _percentiles(ms)
-            s["p50_ms"].append(i50)
-            s["p95_ms"].append(i95)
-            s["p99_ms"].append(i99)
-            s["sla_attainment"].append(float(np.mean(ms <= sla)))
-            im = bool(float(np.quantile(ms, cfg.sla_quantile)) <= sla)
-            s["meets_sla"].append(im)
-            s["n_queries"].append(int(len(ms)))
-            met_t += im
-        s["backlog_s"] = [float(b) for b in backlog_mt[m]]
-        # True = window hit the query cap before the interval end and the
-        # remainder is stationarity-bridged; False = fully simulated
-        # (always False under cfg.event_core unless event_core_queries is
-        # exceeded); None = interval not measured
-        s["bridged"] = bridged_mt[m]
-        n_meas = sum(1 for lat in lat_mt[m] if lat is not None)
-        series[name] = s
-        workloads[name] = {
-            "sla_ms": sla, "p50_ms": p50, "p95_ms": p95, "p99_ms": p99,
-            "sla_attainment": attainment, "meets_sla": bool(meets),
-            "interval_sla_met_frac":
-                float(met_t / n_meas) if n_meas else 0.0,
-            "n_queries": int(len(lat_ms)), "n_hedged": int(n_hedged[m]),
-            "n_retried": int(n_retried[m]),
-        }
-    return DayResult(
-        policy=policy,
-        power=power,
-        capacity=capacity,
-        churn=churn,
-        feasible=feasible,
-        peak_power_w=float(power.max()),
-        avg_power_w=float(power.mean()),
-        peak_capacity=int(capacity.max()),
-        avg_capacity=float(capacity.mean()),
-        resolves=prov.n_resolves,
-        holds=prov.n_holds,
-        tail_resolves=prov.n_tail_resolves,
-        total_churn=int(churn.sum()),
-        per_workload=workloads,
-        series={
-            "interval_s": transitions.interval_s,
-            "per_workload": series,
-        },
-        all_meet_sla=bool(all_meet),
-        events=events,
-        latencies=lat_mt if cfg.collect_latencies else None,
-        co_capacity=co_cap if inputs.colocation is not None else None,
-    )
+    return _day_result(log, prov, policy, table.workloads, slas,
+                       transitions.interval_s, cfg,
+                       inputs.colocation is not None)

@@ -1,7 +1,7 @@
 """Stateful provisioning + the query-granular cluster serving runtime:
 transition-delay accounting, hysteresis, elastic re-provisioning after
-failures, router stream assignment, and PairService <-> fast-engine
-equivalence."""
+failures, router stream assignment, PairService <-> fast-engine
+equivalence, and the day loop's phases on hand-built intervals."""
 import pathlib
 import tempfile
 
@@ -13,8 +13,10 @@ from repro.core import profile_cache
 from repro.core.cluster import (
     EfficiencyTable,
     StatefulProvisioner,
+    StatefulStep,
     TransitionConfig,
 )
+from repro.core.colocation import CoMachine
 from repro.core.devices import SERVER_TYPES
 from repro.core.efficiency import build_table, default_query_sizes
 from repro.core.partition import enumerate_placements
@@ -22,8 +24,12 @@ from repro.serving.cluster_runtime import (
     DayInputs,
     PairService,
     RuntimeConfig,
+    _carry_out,
+    _retire_victims,
+    _slot_pool,
     _state_abs,
     _state_residual,
+    _tail_met,
     simulate_cluster_day,
 )
 from repro.serving.diurnal import diurnal_trace, load_increment_rate
@@ -203,43 +209,29 @@ class TestContinuousTime:
     def test_backlog_persists_across_intervals(self, small_cluster):
         """A fleet pinned just past its feasibility frontier (the re-solve
         is infeasible, the pool serves best-effort at ~103% utilization)
-        accumulates backlog interval over interval under carry-over; the
-        idle-pool reset showed a flat, flattering tail at the exact same
-        offered load."""
+        accumulates backlog interval over interval: the carried state is
+        never reset to an idle pool at an interval boundary."""
         table, records, profiles, servers = small_cluster
         t1 = EfficiencyTable(("T2",), ("dlrm-rmc1",),
                              table.qps[:1, :1], table.power[:1, :1],
                              np.array([4]))
         cap = 4 * float(t1.qps[0, 0])
         traces = np.concatenate([[0.90], np.full(5, 1.03)])[None, :] * cap
-        out = {}
-        for label, cfg in (
-            ("carry", RuntimeConfig(tail_feedback=False)),
-            ("reset", RuntimeConfig(carry_backlog=False,
-                                    hedge_live_queue=False,
-                                    tail_feedback=False)),
-        ):
-            out[label] = _day(
-                t1, records, profiles, traces,
-                servers=servers, overprovision=0.05, config=cfg, seed=0)
-        s_carry = out["carry"].series["per_workload"]["dlrm-rmc1"]
-        s_reset = out["reset"].series["per_workload"]["dlrm-rmc1"]
-        # carried backlog compounds; the reset runtime never sees it
-        assert s_carry["p95_ms"][-1] > 5.0 * s_reset["p95_ms"][-1]
-        assert s_carry["backlog_s"][-1] > 5.0 * s_reset["backlog_s"][-1]
+        out = _day(t1, records, profiles, traces,
+                   servers=servers, overprovision=0.05, seed=0)
+        s = out.series["per_workload"]["dlrm-rmc1"]
         # monotone growth through the overloaded stretch
-        assert s_carry["backlog_s"][1] < s_carry["backlog_s"][2] < \
-            s_carry["backlog_s"][3]
-        # day-level tail inherits the divergence
-        assert out["carry"].per_workload["dlrm-rmc1"]["p99_ms"] >= \
-            out["reset"].per_workload["dlrm-rmc1"]["p99_ms"]
+        backlog = s["backlog_s"]
+        assert all(a < b for a, b in zip(backlog[1:], backlog[2:]))
+        # the carried backlog compounds into the tail
+        assert s["p95_ms"][-1] > 5.0 * s["p95_ms"][0]
 
 
 class TestLiveQueueHedging:
     def test_hedge_rides_the_live_queue(self):
         """A hedge admitted into a busy alternate completes strictly later
-        than the old unloaded-service model said it would: completion >=
-        issue + solo_time, with equality only on an idle pool."""
+        than its unloaded service time: completion >= issue + solo_time,
+        with equality only on an idle pool."""
         cache = SimCache(SIZES, seed=0)
         rec = {"qps": 1000.0, "plan": "cpu_model", "m": 4, "d": 64,
                "o": 2, "sd_sparse": 0}
@@ -282,25 +274,6 @@ class TestLiveQueueHedging:
         assert router.hedge_assign(np.array([1]),
                                    np.array([0.0])).tolist() == [-1]
 
-    def test_day_tail_not_flattered_by_optimistic_hedges(self, small_cluster):
-        table, records, profiles, servers = small_cluster
-        traces = _traces(table, 0.09, 12)
-        R = max(load_increment_rate(t) for t in traces)
-        outs = {}
-        for label, cfg in (
-            ("live", RuntimeConfig()),
-            ("optimistic", RuntimeConfig(hedge_live_queue=False)),
-        ):
-            outs[label] = _day(
-                table, records, profiles, traces,
-                servers=servers, overprovision=R, config=cfg)
-        for name in table.workloads:
-            live = outs["live"].per_workload[name]
-            opt = outs["optimistic"].per_workload[name]
-            # a live-queue hedge can never beat the unloaded-service model
-            assert live["p99_ms"] >= opt["p99_ms"] - 1e-9
-            assert live["n_hedged"] <= opt["n_hedged"]
-
 
 class TestTailFeedback:
     def test_violation_vetoes_hold_and_boosts(self):
@@ -337,21 +310,13 @@ class TestTailFeedback:
                              np.array([6]))
         cap = 6 * float(t1.qps[0, 0])
         traces = np.full((1, 8), 0.60 * cap)
-        outs = {}
-        for label, cfg in (("fb", RuntimeConfig()),
-                           ("nofb", RuntimeConfig(tail_feedback=False))):
-            outs[label] = _day(
-                t1, records, profiles, traces,
-                servers=servers, overprovision=0.05, config=cfg, seed=1)
-        fb, nofb = outs["fb"], outs["nofb"]
-        assert fb.tail_resolves > 0 and nofb.tail_resolves == 0
+        fb = _day(t1, records, profiles, traces,
+                  servers=servers, overprovision=0.05, seed=1)
+        assert fb.tail_resolves > 0
         assert fb.capacity[-1] > fb.capacity[0]             # grew the fleet
-        assert (nofb.capacity == nofb.capacity[0]).all()
-        s_fb = fb.series["per_workload"]["dlrm-rmc1"]
-        s_no = nofb.series["per_workload"]["dlrm-rmc1"]
-        assert s_fb["p95_ms"][-1] < s_no["p95_ms"][-1]      # drained
-        assert fb.per_workload["dlrm-rmc1"]["sla_attainment"] > \
-            nofb.per_workload["dlrm-rmc1"]["sla_attainment"]
+        s = fb.series["per_workload"]["dlrm-rmc1"]
+        assert s["p95_ms"][-1] < max(s["p95_ms"])           # drained
+        assert s["meets_sla"][-1]
 
 
 @pytest.fixture(scope="module")
@@ -598,3 +563,104 @@ class TestEventCoreDay:
         assert n_hedged > 0
         for w in ev.per_workload.values():
             assert w["p99_ms"] > 0.0 and np.isfinite(w["p99_ms"])
+
+
+class _StubService:
+    """What the slot pool reads of a service: its rate and an idle state."""
+
+    def __init__(self, qps):
+        self.qps = qps
+
+    def fresh_state(self):
+        return {"pool": np.zeros(2)}
+
+
+def _step(alloc, added, removed, coalloc=(), co_added=(), co_removed=()):
+    return StatefulStep(
+        alloc=np.array(alloc), added=np.array(added),
+        removed=np.array(removed), capacity=0, power_w=0.0, feasible=True,
+        resolved=False, coalloc=coalloc, co_added=co_added,
+        co_removed=co_removed)
+
+
+def _co(server, rate):
+    return CoMachine(server=server, tenants=("a", "b"), rates=(rate, 1.0),
+                     qps=(100.0, 100.0), dilation=(1.25, 1.25),
+                     power_w=300.0)
+
+
+class TestDayPhases:
+    """The day loop's phases on hand-built intervals."""
+
+    TR = TransitionConfig(model_load_s=120.0, drain_s=150.0)
+
+    def test_slot_pool_carries_kept_and_gates_added(self):
+        """Kept machines resume their carried (group, i) states, an added
+        one starts idle once its model is loaded, a draining one inherits
+        the next carried state and retires at the drain deadline; shared
+        machines follow the solo ones with their machine identity."""
+        svcs = {}
+
+        def service(m, server, dilation=1.0):
+            return svcs.setdefault((server, dilation),
+                                   _StubService(10.0 * dilation))
+
+        kept_c, added_c, drain_c = _co("T7", 4.0), _co("T7", 5.0), \
+            _co("T7", 6.0)
+        step = _step([[3], [0]], [[1], [0]], [[1], [0]],
+                     coalloc=(added_c, kept_c), co_added=(added_c,),
+                     co_removed=(drain_c,))
+        g = ("c", "T7", ("a", "b"))
+        prev = {(0, 0): {"pool": np.array([1.0, 2.0])},
+                (0, 1): {"pool": np.array([3.0, 0.0])},
+                (0, 2): {"pool": np.array([5.0, 4.0])},
+                (g, 0): {"pool": np.array([7.0, 0.5])}}
+        t0 = 900.0
+        pool = _slot_pool(step, 0, "a", t0, self.TR, prev, service,
+                          ("T2", "T3"))
+        load, drain = t0 + 120.0, t0 + 150.0
+        assert [s.server_type for s in pool.slots] == ["T2"] * 4 + ["T7"] * 3
+        assert [s.ready_at for s in pool.slots] == \
+            [t0, t0, load, t0, t0, load, t0]
+        assert [s.retire_at for s in pool.slots] == \
+            [np.inf] * 3 + [drain] + [np.inf] * 2 + [drain]
+        assert [s.qps for s in pool.slots] == [10.0] * 4 + [4.0, 5.0, 6.0]
+        assert [s.machine for s in pool.slots] == \
+            [None] * 4 + [g + (0,), g + (1,), g + (2,)]
+        assert pool.keys == [(0, 0), (0, 1), (0, 2), None,
+                             (g, 0), (g, 1), None]
+        want = [[1.0, 2.0], [3.0, 0.0], [0.0, 0.0], [5.0, 4.0],
+                [7.0, 0.5], [0.0, 0.0], [0.0, 0.0]]
+        for st, w in zip(pool.states, want):
+            assert st["pool"].tolist() == (t0 + np.array(w)).tolist()
+        assert pool.pair_of[4] is svcs[("T7", 1.25)]
+
+    def test_carry_out_drops_failed_and_draining_and_compacts(self):
+        """A machine failed mid-window and a draining one carry nothing
+        out; the survivors' residual states move to compacted indices."""
+        step = _step([[3]], [[0]], [[1]])
+        prev = {(0, i): {"pool": np.array([float(i), 0.0])}
+                for i in range(4)}
+        pool = _slot_pool(step, 0, "a", 0.0, self.TR, prev,
+                          lambda m, server, dilation=1.0: _StubService(1.0),
+                          ("T2",))
+        pool.states[1]["pool"] += 100.0      # served past the window end
+        arrivals = np.linspace(0.0, 10.0, 11)
+        fails = _retire_victims(pool, [(0, 0.5)], arrivals)
+        assert fails == [(0, 5.0)]           # the first serving T2 died
+        assert pool.slots[0].retire_at == 5.0
+        carried, backlog = _carry_out(pool.states, pool.keys, 10.0)
+        assert set(carried) == {(0, 0), (0, 1)}
+        assert carried[(0, 0)]["pool"].tolist() == [91.0, 90.0]
+        assert carried[(0, 1)]["pool"].tolist() == [0.0, 0.0]
+        assert backlog == 91.0
+
+    def test_tail_check_fails_on_a_lost_query(self):
+        """One lost (infinite-latency) query fails the interval, even where
+        the SLA quantile itself is finite and within the SLA."""
+        ok = np.full(100, 0.001)
+        lost = np.append(ok[:-1], np.inf)
+        assert np.quantile(lost, 0.95) * 1e3 < 20.0
+        assert _tail_met([ok, None], [20.0, 50.0], 0.95)
+        assert not _tail_met([lost, None], [20.0, 50.0], 0.95)
+        assert not _tail_met([ok, np.full(10, 0.06)], [20.0, 50.0], 0.95)

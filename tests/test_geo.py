@@ -1,8 +1,7 @@
 """Geo-distributed serving (`repro.serving.geo`): spill-plan conservation
 and limits, origin attribution with the link RTT added exactly once,
 partition/drain event semantics, the follow-the-sun power win over the
-per-region-isolated baseline, spec serialization, and the deprecated
-simulate_cluster_day kwarg shim reproducing the typed path bitwise."""
+per-region-isolated baseline, and spec serialization."""
 import json
 import pathlib
 import tempfile
@@ -12,7 +11,6 @@ import pytest
 
 from repro.core import profile_cache
 from repro.serving import scenarios as sc
-from repro.serving.cluster_runtime import simulate_cluster_day
 from repro.serving.geo import GeoConfig, plan_spill
 from repro.serving.router import split_stream_by_share
 from repro.serving.scenarios import (
@@ -248,40 +246,6 @@ class TestGeoSpecSerialization:
         assert back == spec
         assert back.regions == spec.regions
         assert back.links == spec.links
-
-
-class TestDeprecatedKwargShim:
-    def test_old_signature_warns_and_matches_bitwise(self):
-        """The pre-DayInputs call shape still works, warns, and reproduces
-        the typed path bit for bit on the golden baseline_day."""
-        comp = compile_scenario(get_scenario("baseline_day"))
-        inp = comp.inputs
-        new = simulate_cluster_day(inp, policy="hercules")
-        with pytest.warns(DeprecationWarning, match="DayInputs"):
-            old = simulate_cluster_day(
-                inp.table, inp.records, inp.profiles, inp.traces,
-                policy="hercules", servers=inp.servers,
-                overprovision=inp.overprovision,
-                transitions=inp.transitions, failures=inp.failures,
-                seed=inp.seed)
-        a, b = old.to_dict(), new.to_dict()
-        assert a.keys() == b.keys()
-
-        def eq(x, y):
-            if isinstance(x, dict):
-                assert x.keys() == y.keys()
-                for k in x:
-                    eq(x[k], y[k])
-            elif isinstance(x, np.ndarray) or isinstance(y, np.ndarray):
-                assert np.array_equal(x, y)
-            elif isinstance(x, (list, tuple)):
-                assert len(x) == len(y)
-                for xx, yy in zip(x, y):
-                    eq(xx, yy)
-            else:
-                assert x == y
-
-        eq(a, b)
 
 
 class TestWithAvailability:
